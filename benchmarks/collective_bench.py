@@ -2,10 +2,11 @@
 schedules (ring AllGather, bidir ring, linear/pairwise AlltoAll, ring
 AllReduce, incast) on an 8-device host mesh.
 
-jax pins the device count at first init, and benches must see 1 device in
-this process (the brief); the timing therefore runs in one subprocess with
-``--xla_force_host_platform_device_count=8``, exactly like the multi-device
-tests.
+This is a rehearsal on CPU virtual devices, not a device measurement:
+jax pins the device count at first init, so the timing runs in one
+subprocess with ``JAX_PLATFORMS=cpu`` and
+``--xla_force_host_platform_device_count=8``, exactly like the
+multi-device tests. The child never contends for an accelerator.
 """
 from __future__ import annotations
 
@@ -81,6 +82,7 @@ def run_all(sizes) -> list:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", _SCRIPT, json.dumps(sizes)],
                        env=env, capture_output=True, text=True, timeout=900)
     assert r.returncode == 0, r.stderr[-3000:]
@@ -100,7 +102,7 @@ def main(force: bool = False):
 
     rows = cached_sweep("collective_bench", ["size"], cache_points, run_size,
                         force=force)
-    print("\n# §III-B — custom collective schedules, 8 host devices "
+    print("\n# §III-B — custom collective schedules, 8 cpu virtual devices "
           "(us/call)")
     colls = [k for k in rows[0] if k != "size"]
     print(f"{'size':>8} " + " ".join(f"{c:>22}" for c in colls))

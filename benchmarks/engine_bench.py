@@ -217,13 +217,6 @@ def main(argv=None):
                     help="allowed relative ratio regression (default 10%%)")
     ap.add_argument("--write", action="store_true",
                     help="write --out even in --check-against mode")
-    ap.add_argument("--sharded", action="store_true",
-                    help="also run the sharded-launch smoke "
-                    "(launch/sweep.py): single vs sharded cold vs "
-                    "sharded warm children; rows land under "
-                    "result['sharded']")
-    ap.add_argument("--sharded-devices", type=int, default=8,
-                    help="forced host device count for --sharded")
     args = ap.parse_args(argv)
 
     scales = SCALES_QUICK if args.quick else SCALES_FULL
@@ -239,30 +232,13 @@ def main(argv=None):
         "aggressor": "incast",
         "vector_bytes": VECTOR_BYTES,
         "jax_backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
+        "device_count": len(jax.devices()),
         "pallas_interpret": jax.default_backend() != "tpu",
         "quick": args.quick,
         "wall_s": round(time.time() - t0, 1),
         "scales": rows,
     }
-
-    sharded_ok = True
-    if args.sharded:
-        # children force their own host-device count; this process keeps
-        # its backend untouched (run_smoke only orchestrates subprocesses)
-        from repro.launch.sweep import run_smoke
-        print(f"sharded-launch smoke: {args.sharded_devices} host devices "
-              "(single vs sharded-cold vs sharded-warm children)")
-        report = run_smoke(args.sharded_devices)
-        result["sharded"] = report
-        sharded_ok = report["ok"]
-        for k in ("single", "sharded_cold", "sharded_warm"):
-            r = report[k]
-            print(f"  {k:13s} dev={r['n_devices']} "
-                  f"wall={r['wall_first_s']:.2f}s "
-                  f"steady={r['wall_second_s']:.2f}s "
-                  f"compile={r['compile_s']:.2f}s "
-                  f"cache={r['cache_hits']}h/{r['cache_misses']}m")
-        print(f"  checks: {report['checks']}")
 
     bad_parity = [r["n_nodes"] for r in rows if r["parity"] != "OK"]
     failures = []
@@ -277,10 +253,6 @@ def main(argv=None):
         return 1
     if failures:
         print("PERF REGRESSION:\n  " + "\n  ".join(failures),
-              file=sys.stderr)
-        return 1
-    if not sharded_ok:
-        print("SHARDED-LAUNCH SMOKE FAILED (see checks above)",
               file=sys.stderr)
         return 1
     print("engine_bench: OK")

@@ -244,6 +244,8 @@ def main(argv=None):
         "schema": 1,
         "quick": args.quick,
         "jax_backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
+        "device_count": len(jax.devices()),
         "point_keys": POINT_KEYS["fleet_replay"],
         "wall_s": round(time.time() - t0, 1),
         "seed_counts": rows,

@@ -230,6 +230,8 @@ def main(argv=None):
         "schema": 1,
         "quick": args.quick,
         "jax_backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
+        "device_count": len(jax.devices()),
         "wall_s": round(time.time() - t0, 1),
         "convergence": conv,
         "coalescing": coal,

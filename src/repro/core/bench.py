@@ -360,6 +360,22 @@ def _cell_dts(case: GridCase, sizes: Sequence[float], n_profiles: int,
     return dts
 
 
+def grid_params(case: GridCase, sizes: Sequence[float],
+                profiles: Sequence[cong.Profile], dt: Optional[float] = None,
+                ) -> Tuple[List[float], SimParams]:
+    """One grid's lanes: per size a baseline (aggressors/background jobs
+    off) then one lane per profile, stacked into one SimParams. Returns
+    the per-lane dts and the stack. Any faulted lane forces the inert
+    fault table on its siblings (one pytree structure per stack)."""
+    with_ft = cong.needs_fault_table(profiles)
+    dts = _cell_dts(case, sizes, len(profiles), dt, case.lat())
+    cells = [(float(v), prof) for v in sizes
+             for prof in [cong.no_congestion()] + list(profiles)]
+    return dts, stack_params([case.cell_params(v, prof, d,
+                                               with_fault_table=with_ft)
+                              for (v, prof), d in zip(cells, dts)])
+
+
 def _grid_results(case: GridCase, out: dict, sizes: Sequence[float],
                   profiles: Sequence[cong.Profile], dts: Sequence[float], *,
                   n_iters: int, warmup: int, chunk: int, stride: int,
@@ -452,19 +468,12 @@ def run_grid(system: Union[SystemPreset, Sequence[ScaleCell]], n_nodes: int,
                               trace_stride=trace_stride, phased=phased,
                               jobs=jobs, mesh=mesh, launcher=launcher)
     check_iter_budget(n_iters)
-    # fault/intra-node lanes: any faulted lane forces the inert table on
-    # its siblings (one pytree structure per stack); any node-capped lane
-    # arms the intra-node stage for the whole case (inert at inf)
-    with_ft = cong.needs_fault_table(profiles)
+    # any node-capped lane arms the intra-node stage for the whole case
+    # (inert at inf)
     case = build_case(system, n_nodes, victim_coll, aggr_coll,
                       phased=phased, jobs=jobs,
                       intra_node=any(p.node_cap_frac > 0 for p in profiles))
-    dts = _cell_dts(case, sizes, len(profiles), dt, case.lat())
-    cells = [(float(v), prof) for v in sizes
-             for prof in [cong.no_congestion()] + list(profiles)]
-    params = stack_params([case.cell_params(v, prof, d,
-                                            with_fault_table=with_ft)
-                           for (v, prof), d in zip(cells, dts)])
+    dts, params = grid_params(case, sizes, profiles, dt)
     max_chunks = -(-max_steps // chunk)
     out = run_cells(case.geom, params, jnp.asarray(n_iters, jnp.int32),
                     chunk=chunk, max_chunks=max_chunks, stride=trace_stride)

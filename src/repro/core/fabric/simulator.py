@@ -128,20 +128,23 @@ def check_iter_budget(n_iters: int) -> None:
 # ---------------------------------------------------------------------------
 # Persistent compilation cache: reruns of the engine skip XLA compilation
 # entirely (the jaxpr trace still runs, but it is milliseconds next to the
-# multi-second XLA compile of the chunked while_loop). Enabled either
-# explicitly (launch.sweep / launch.dryrun) or ambiently via
-# $REPRO_COMPILE_CACHE_DIR, which every public engine entry checks lazily.
+# multi-second XLA compile of the chunked while_loop). Every public engine
+# entry arms it lazily. The directory is placed from outside only:
+# $JAX_COMPILATION_CACHE_DIR when set, else one fixed path inside the
+# checkout (a cache directory is part of the entries' key, so it must not
+# move between runs).
 # ---------------------------------------------------------------------------
 
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_COMPILE_CACHE_DIR = os.path.abspath(os.path.join(
+    os.path.dirname(__file__), "..", "..", "..", "..", ".jax_cache"))
 _COMPILE_CACHE_DIR: Optional[str] = None
-COMPILE_CACHE_ENV = "REPRO_COMPILE_CACHE_DIR"
 
 
-def ensure_compile_cache(cache_dir: Optional[str] = None, *,
-                         min_compile_secs: float = 0.0) -> Optional[str]:
-    """Point XLA's persistent compilation cache at ``cache_dir`` (or
-    ``$REPRO_COMPILE_CACHE_DIR``). Idempotent and cheap once configured;
-    returns the active cache dir, or None when neither source names one.
+def ensure_compile_cache(*, min_compile_secs: float = 0.0) -> str:
+    """Arm XLA's persistent compilation cache at ``$JAX_COMPILATION_CACHE_DIR``
+    or, when that is unset, at ``DEFAULT_COMPILE_CACHE_DIR``. Idempotent
+    and cheap once configured; returns the active cache dir.
     ``min_entry_size_bytes=-1`` caches every entry regardless of size —
     on CPU the engine executables are small but cost seconds to build."""
     global _COMPILE_CACHE_DIR
@@ -149,18 +152,12 @@ def ensure_compile_cache(cache_dir: Optional[str] = None, *,
         # first activation wins: a process-wide cache must not silently
         # re-point mid-run (half the entries would land elsewhere)
         return _COMPILE_CACHE_DIR
-    cache_dir = cache_dir or os.environ.get(COMPILE_CACHE_ENV)
-    if not cache_dir:
-        return None
-    cache_dir = os.path.abspath(cache_dir)
+    cache_dir = os.environ.get(COMPILE_CACHE_ENV) or DEFAULT_COMPILE_CACHE_DIR
     os.makedirs(cache_dir, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       float(min_compile_secs))
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except AttributeError:  # pragma: no cover - older jax without the knob
-        pass
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _COMPILE_CACHE_DIR = cache_dir
     return cache_dir
 

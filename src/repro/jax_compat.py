@@ -1,25 +1,14 @@
-"""Polyfills bridging jax 0.4.x and the 0.5+/0.6 APIs the codebase uses.
+"""Host-device count for CPU rehearsals of multi-device paths.
 
-Imported from ``repro/__init__.py`` so any entry point (tests, benchmarks,
-subprocess scripts) gets the shims as soon as a ``repro`` module loads.
-Newer jax versions are left untouched.
-
-* ``jax.shard_map``  — moved out of ``jax.experimental.shard_map`` in 0.5;
-  the keyword ``check_rep`` was renamed ``check_vma``.
-* ``jax.set_mesh``   — 0.6 context manager; on 0.4.x a ``Mesh`` is itself
-  the context manager that installs the physical mesh.
-
-``force_host_device_count`` lives here too: the one sanctioned way to
-request N host platform devices. It must run before the jax backend
-initializes (importing jax is fine; the flag is read at first device
-query), and it APPENDS to ``XLA_FLAGS`` — user-set flags survive, and an
-existing device-count flag is replaced rather than duplicated.
+``force_host_device_count`` is the one sanctioned way to request N host
+platform devices. It must run before the jax backend initializes
+(importing jax is fine; the flag is read at first device query), and it
+APPENDS to ``XLA_FLAGS`` — user-set flags survive, and an existing
+device-count flag is replaced rather than duplicated.
 """
 from __future__ import annotations
 
 import os
-
-import jax
 
 _DEVICE_COUNT_FLAG = "--xla_force_host_platform_device_count"
 
@@ -31,26 +20,3 @@ def force_host_device_count(n: int) -> None:
              if not f.startswith(_DEVICE_COUNT_FLAG)]
     flags.append(f"{_DEVICE_COUNT_FLAG}={int(n)}")
     os.environ["XLA_FLAGS"] = " ".join(flags)
-
-
-def install() -> None:
-    if not hasattr(jax, "shard_map"):
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        def shard_map(f, *, mesh=None, in_specs=None, out_specs=None,
-                      check_vma=None, **kw):
-            if check_vma is not None:
-                kw["check_rep"] = check_vma
-            return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-
-        jax.shard_map = shard_map
-
-    if not hasattr(jax, "set_mesh"):
-        def set_mesh(mesh):
-            return mesh  # Mesh.__enter__ installs it (0.4.x semantics)
-
-        jax.set_mesh = set_mesh
-
-
-install()

@@ -20,7 +20,10 @@ per-link state resident in VMEM across hops (DESIGN.md §13):
 * Segment-max (``sw_sat``) uses the same mask with a masked ``jnp.max``
   (order-independent, so it is exact vs the reference scatter-max).
 * The H-hop loop is unrolled in-kernel (H is static geometry meta); the
-  per-flow rate vector ``r`` never leaves registers/VMEM between hops.
+  flow- and link-block loops are ``lax.fori_loop``s over 128-aligned
+  slices, so the kernel body (and its compile time) does not grow with
+  F x L. The per-flow rate vector ``r`` lives in the ``achieved`` output
+  ref, updated hop by hop, and never leaves VMEM.
 
 Exactness contract: identical arithmetic to ``kernels.ref.fabric_step_core``
 except that one-hot contractions may sum a link's contributions in a
@@ -31,7 +34,8 @@ and in interpret mode; ``REPRO_FABRIC_KERNEL=pallas`` (or
 ``simulator.set_step_backend``) routes the engine through this kernel.
 
 VMEM budget (defaults, fp32): the dominant residents are one
-(block_flows, L+1) one-hot tile (128 x 4096 -> 2 MiB), the per-link rows
+(block_flows, L+1) one-hot tile (128 x 4096 -> 2 MiB; 7.1 MiB at the
+4096-node engine cell's L=14559), the per-link rows
 (q/occ/caps/arrival/load: 6 x (L+1) -> ~100 KiB at L=4096), and the
 per-flow rows (~4 x F). Flow/link axes are padded to block multiples with
 provably inert rows (pad flows inject 0 onto the sink; pad links have
@@ -58,6 +62,13 @@ def _onehot(idx, n_out):
     return (idx[:, None] == ids).astype(jnp.float32)
 
 
+def _dot(a, b):
+    """fp32 contraction at full precision: the MXU's default f32 pass
+    rounds operands to bf16, which would round the rates it carries."""
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
 def _kernel(plinks_ref, inject_ref, src_id_ref, host_caps_ref, q_ref,
             occ_ref, caps_finite_ref, src_sw_ref, dst_sw_ref, s_ref,
             *out_refs, sink: int, n_src: int, n_sw: int, bf: int, bl: int,
@@ -69,101 +80,113 @@ def _kernel(plinks_ref, inject_ref, src_id_ref, host_caps_ref, q_ref,
     hol_start = s_ref[0, 3]
     burst_jitter = s_ref[0, 4]
 
-    F, H = plinks_ref.shape          # flow axis padded to a bf multiple
+    H, F = plinks_ref.shape          # flow axis padded to a bf multiple
     Lp = q_ref.shape[1]              # link axis padded to a bl multiple
     n_fb, n_lb = F // bf, Lp // bl
+    zeros_l = jnp.zeros((1, Lp), jnp.float32)
+
+    # Block loops are lax.fori_loops over aligned dynamic slices, so the
+    # kernel body stays the same size at any F and L; blocks are visited
+    # in index order, so every accumulation sums in the same order as a
+    # fully unrolled loop would.
+    def fsl(fb):
+        return pl.ds(pl.multiple_of(fb * bf, bf), bf)
+
+    def lsl(lb):
+        return pl.ds(pl.multiple_of(lb * bl, bl), bl)
 
     # ---- NIC limit: src_load segment-sum, then per-flow gather+scale ----
-    src_load = jnp.zeros((1, n_src), jnp.float32)
-    for fb in range(n_fb):
-        sl = slice(fb * bf, (fb + 1) * bf)
+    def src_sum(fb, src_load):
+        sl = fsl(fb)
         sel = _onehot(src_id_ref[0, sl], n_src)
-        src_load = src_load + jnp.dot(
-            inject_ref[0, sl][None, :], sel,
-            preferred_element_type=jnp.float32)
-    inj_blocks = []
-    for fb in range(n_fb):
-        sl = slice(fb * bf, (fb + 1) * bf)
+        return src_load + _dot(inject_ref[0, sl][None, :], sel)
+
+    src_load = jax.lax.fori_loop(0, n_fb, src_sum,
+                                 jnp.zeros((1, n_src), jnp.float32))
+
+    def nic_scale(fb, carry):
+        sl = fsl(fb)
         sel = _onehot(src_id_ref[0, sl], n_src)
-        mine = jnp.dot(sel, src_load.T,
-                       preferred_element_type=jnp.float32)[:, 0]
+        mine = _dot(sel, src_load.T)[:, 0]
         scale = jnp.minimum(1.0, host_caps_ref[0, sl]
                             / jnp.maximum(mine, 1.0))
-        inj_blocks.append((inject_ref[0, sl] * scale)[None, :])
-    inject = jnp.concatenate(inj_blocks, axis=1)  # (1, F), NIC-scaled
-    inject_out_ref[...] = inject
+        inj = (inject_ref[0, sl] * scale)[None, :]
+        inject_out_ref[:, sl] = inj  # (1, F), NIC-scaled
+        a_ref[:, sl] = inj  # the per-flow rate r, updated hop by hop
+        return carry
+
+    jax.lax.fori_loop(0, n_fb, nic_scale, 0)
 
     # ---- backpressure: hot_q/tot_q segment-sums + sw_sat segment-max ----
-    q_row = q_ref[...]
-    occ_row = occ_ref[...]
-    hot_q = jnp.zeros((1, n_sw), jnp.float32)
-    tot_q = jnp.zeros((1, n_sw), jnp.float32)
-    sw_sat = jnp.zeros((1, n_sw), jnp.float32)
-    for lb in range(n_lb):
-        sl = slice(lb * bl, (lb + 1) * bl)
-        sat_b = jnp.clip((occ_row[0, sl] - hol_start)
+    def sw_reduce(lb, carry):
+        hot_q, tot_q, sw_sat = carry
+        sl = lsl(lb)
+        sat_b = jnp.clip((occ_ref[0, sl] - hol_start)
                          / (1.0 - hol_start), 0.0, 1.0)
-        q_b = q_row[0, sl]
+        q_b = q_ref[0, sl]
         sel = _onehot(src_sw_ref[0, sl], n_sw)
-        hot_q = hot_q + jnp.dot((q_b * sat_b)[None, :], sel,
-                                preferred_element_type=jnp.float32)
-        tot_q = tot_q + jnp.dot(q_b[None, :], sel,
-                                preferred_element_type=jnp.float32)
+        hot_q = hot_q + _dot((q_b * sat_b)[None, :], sel)
+        tot_q = tot_q + _dot(q_b[None, :], sel)
         # masked max: exact (order-free) surrogate of .at[].max on zeros
         sw_sat = jnp.maximum(
             sw_sat, jnp.max(sel * sat_b[:, None], axis=0)[None, :])
+        return hot_q, tot_q, sw_sat
+
+    zeros_sw = jnp.zeros((1, n_sw), jnp.float32)
+    hot_q, tot_q, sw_sat = jax.lax.fori_loop(
+        0, n_lb, sw_reduce, (zeros_sw, zeros_sw, zeros_sw))
     share = hot_q / jnp.maximum(tot_q, 1.0)
     stall = 1.0 - hol_factor * sw_sat * share
     sw_ids = jax.lax.broadcasted_iota(jnp.int32, (1, n_sw), 1)
     stall = jnp.where(sw_ids == 0, 1.0, stall)  # 0 == host endpoint
-    ce_blocks = []
-    for lb in range(n_lb):
-        sl = slice(lb * bl, (lb + 1) * bl)
+
+    def stall_caps(lb, carry):
+        sl = lsl(lb)
         sel = _onehot(dst_sw_ref[0, sl], n_sw)
-        st = jnp.dot(sel, stall.T, preferred_element_type=jnp.float32)[:, 0]
-        ce_blocks.append((caps_finite_ref[0, sl] * st)[None, :])
-    caps_eff = jnp.concatenate(ce_blocks, axis=1)  # (1, Lp)
-    caps_eff_ref[...] = caps_eff
+        st = _dot(sel, stall.T)[:, 0]
+        caps_eff_ref[:, sl] = (caps_finite_ref[0, sl] * st)[None, :]
+        return carry
+
+    jax.lax.fori_loop(0, n_lb, stall_caps, 0)
+    caps_eff = caps_eff_ref[...]  # (1, Lp)
 
     # ---- H-hop staged propagation: flow rows resident across hops ----
-    r = inject
-    arrival = jnp.zeros((1, Lp), jnp.float32)
-    served_max = jnp.zeros((1, Lp), jnp.float32)
+    arrival = zeros_l
+    served_max = zeros_l
     for h in range(H):
-        load = jnp.zeros((1, Lp), jnp.float32)
-        for fb in range(n_fb):
-            sl = slice(fb * bf, (fb + 1) * bf)
-            lk = plinks_ref[sl, h]
-            contrib = r[0, sl] * (lk < sink).astype(jnp.float32)
-            load = load + jnp.dot(contrib[None, :], _onehot(lk, Lp),
-                                  preferred_element_type=jnp.float32)
+        def link_load(fb, load, h=h):
+            sl = fsl(fb)
+            lk = plinks_ref[h, sl]
+            contrib = a_ref[0, sl] * (lk < sink).astype(jnp.float32)
+            return load + _dot(contrib[None, :], _onehot(lk, Lp))
+
+        load = jax.lax.fori_loop(0, n_fb, link_load, zeros_l)
         arrival = arrival + load
         over = jnp.maximum(load / caps_eff, 1.0)
-        r_blocks = []
-        served = jnp.zeros((1, Lp), jnp.float32)
-        for fb in range(n_fb):
-            sl = slice(fb * bf, (fb + 1) * bf)
-            lk = plinks_ref[sl, h]
+
+        def share_rate(fb, served, h=h, over=over):
+            sl = fsl(fb)
+            lk = plinks_ref[h, sl]
             validh = lk < sink
             sel = _onehot(lk, Lp)
-            og = jnp.dot(sel, over.T,
-                         preferred_element_type=jnp.float32)[:, 0]
-            r_b = jnp.where(validh, r[0, sl] / og, r[0, sl])
-            r_blocks.append(r_b[None, :])
+            og = _dot(sel, over.T)[:, 0]
+            r = a_ref[0, sl]
+            r_b = jnp.where(validh, r / og, r)
+            a_ref[:, sl] = r_b[None, :]
             if with_aux:
-                served = served + jnp.dot(
-                    (r_b * validh.astype(jnp.float32))[None, :], sel,
-                    preferred_element_type=jnp.float32)
-        r = jnp.concatenate(r_blocks, axis=1)
+                served = served + _dot(
+                    (r_b * validh.astype(jnp.float32))[None, :], sel)
+            return served
+
+        served = jax.lax.fori_loop(0, n_fb, share_rate, zeros_l)
         if with_aux:
             served_max = jnp.maximum(served_max, served)
-    a_ref[...] = r
     arrival_ref[...] = arrival
 
     # ---- queue update ----
     link_ids = jax.lax.broadcasted_iota(jnp.int32, (1, Lp), 1)
-    q_new = jnp.clip(q_row + (arrival * (1.0 + burst_jitter)
-                              - caps_eff) * dt,
+    q_new = jnp.clip(q_ref[...] + (arrival * (1.0 + burst_jitter)
+                                   - caps_eff) * dt,
                      0.0, qmax_bytes)
     qnew_ref[...] = jnp.where(link_ids == sink, 0.0, q_new)
     if with_aux:
@@ -192,8 +215,10 @@ def fabric_step_core(plinks, inject, src_id, host_caps, q, occ, caps_finite,
     F, H = plinks.shape
     Lp1 = q.shape[0]
     sink = Lp1 - 1
-    bf = min(block_flows, _round_up(max(F, 1), 8))
-    bl = min(block_links, _round_up(Lp1, 8))
+    # blocks are whole 128-lane tiles: Mosaic slices the lane axis only
+    # at offsets it can prove are multiples of 128
+    bf = min(block_flows, _round_up(max(F, 1), 128))
+    bl = min(block_links, _round_up(Lp1, 128))
     Fp, Lp = _round_up(max(F, 1), bf), _round_up(Lp1, bl)
 
     def pad_f(x, value, dtype):
@@ -204,8 +229,10 @@ def fabric_step_core(plinks, inject, src_id, host_caps, q, occ, caps_finite,
 
     # inert padding: pad flows inject 0 onto the sink from source 0; pad
     # links carry cap 1 / queue 0 and hang off switch 0 (the host bucket)
-    plinks_p = jnp.pad(plinks.astype(jnp.int32),
-                       ((0, Fp - F), (0, 0)), constant_values=sink)
+    # path table hop-major (H, Fp): each hop's link ids are one lane-dense
+    # row of the kernel's flow blocks
+    plinks_p = jnp.pad(plinks.astype(jnp.int32).T,
+                       ((0, 0), (0, Fp - F)), constant_values=sink)
     args = (
         plinks_p,
         pad_f(inject, 0.0, jnp.float32)[None, :],

@@ -30,10 +30,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, block_q: int, block_kv: int,
 
     def body(ci, carry):
         acc, m, l = carry
-        kc = pl.load(k_ref, (pl.dslice(ci * block_kv, block_kv),
-                             slice(None))).astype(jnp.float32)  # (bkv, D)
-        vc = pl.load(v_ref, (pl.dslice(ci * block_kv, block_kv),
-                             slice(None))).astype(jnp.float32)
+        kc = k_ref[pl.ds(ci * block_kv, block_kv), :].astype(
+            jnp.float32)  # (bkv, D)
+        vc = v_ref[pl.ds(ci * block_kv, block_kv), :].astype(jnp.float32)
         s = jax.lax.dot_general(q.reshape(-1, d), kc,
                                 (((1,), (1,)), ((), ())))  # (bq*G, bkv)
         s = s.reshape(block_q, g, block_kv)

@@ -37,8 +37,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
 
     from repro.core.fabric.simulator import ensure_compile_cache
 
-    ensure_compile_cache(os.path.join(ARTIFACTS, "..", "xla_cache"),
-                         min_compile_secs=10.0)
+    ensure_compile_cache(min_compile_secs=10.0)
 
     from repro.configs import get_config
     from repro.configs.base import SHAPES
@@ -191,8 +190,9 @@ def main():
             if args.variant:
                 cmd += ["--variant", args.variant]
             try:
+                # children compile for 512 forced CPU host devices
                 r = subprocess.run(cmd, timeout=args.timeout,
-                                   env=dict(os.environ),
+                                   env=dict(os.environ, JAX_PLATFORMS="cpu"),
                                    capture_output=True, text=True)
                 if r.returncode != 0:
                     failed += 1

@@ -7,21 +7,14 @@ importing this module never touches jax device state — the dry-run sets
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types on mesh construction
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: meshes are implicitly Auto
-    AxisType = None
+from jax.sharding import AxisType
 
 from repro.models.layers import AxisRules
 
 
 def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(shape))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types (its default is Explicit)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -13,28 +13,26 @@ overlaps host-side result marshalling with device compute:
   marshalling shard 0 overlaps compute of shards 1..N. Because the
   per-shard executables are the unpartitioned single-device program and
   vmapped ``while_loop`` lanes are independent (finished lanes freeze),
-  this path is BIT-IDENTICAL to the single-device run — asserted by the
-  ``--smoke`` orchestration and CI.
+  this path is BIT-IDENTICAL to the single-device run — asserted by
+  :func:`run_smoke` on CPU virtual devices and by ``chip_smoke.py
+  --four-chips`` on four chips.
 * **shard_map dispatch** (``dispatch='shard_map'``) — one jitted
   ``jax.shard_map`` call over the mesh (simulator.run_cells_hetero's
-  ``mesh=`` entry, via the jax_compat polyfill). On a multi-device mesh
+  ``mesh=`` entry). On a multi-device mesh
   XLA's *partitioned* compile reassociates the step's float accumulators
   by ~1 ulp vs the unpartitioned executable (deterministic; measured in
   DESIGN.md §14), so this mode is exact only on 1-device meshes and
   ulp-close otherwise.
 
-The launcher also owns the persistent-compile-cache promotion: children
-and drivers call :func:`simulator.ensure_compile_cache` (or set
-``$REPRO_COMPILE_CACHE_DIR``) so a relaunched sweep skips XLA
-compilation entirely — the ``--smoke`` mode demonstrates the cold/warm
-delta across fresh processes.
+A relaunched sweep skips XLA compilation through the engine's
+persistent compile cache (:func:`simulator.ensure_compile_cache`).
+:func:`run_smoke` demonstrates the cold/warm delta across fresh
+processes; it is a CPU rehearsal on forced host devices, run from the
+tests with a fresh work directory.
 
 CLI:
-  PYTHONPATH=src python -m repro.launch.sweep --smoke --host-devices 8
-      # orchestrates single-device vs sharded children (fresh processes),
-      # asserts bit-identity and a persistent-cache compile-time cut
-  PYTHONPATH=src python -m repro.launch.sweep --child ...
-      # one measured workload process (used by --smoke / engine_bench)
+  PYTHONPATH=src python -m repro.launch.sweep --out child.json ...
+      # one measured workload process (spawned by run_smoke)
 """
 from __future__ import annotations
 
@@ -45,7 +43,6 @@ import json
 import os
 import subprocess
 import sys
-import tempfile
 import time
 from collections.abc import Mapping
 
@@ -260,7 +257,7 @@ def run_workload(mesh, *, tiny: bool, dispatch: str = "devices") -> dict:
     }
 
 
-def _compile_meter():
+def compile_meter():
     """Tap jax's own monitoring events for a noise-free compile
     measurement. ``/jax/core/compile/backend_compile_duration`` wraps
     ``compile_or_get_cached``: on a persistent-cache miss it times the
@@ -292,18 +289,17 @@ def _compile_meter():
 
 
 def child_main(args) -> dict:
-    """One measured process: optional forced host-device count +
-    persistent compile cache, workload run twice (rerun digest must
-    match — determinism assert). Compile cost is read from jax's
-    monitoring events (see ``_compile_meter``), not inferred from wall
+    """One measured process: optional forced host-device count, the
+    persistent compile cache its environment places, workload run twice
+    (rerun digest must match — determinism assert). Compile cost is read from jax's
+    monitoring events (see ``compile_meter``), not inferred from wall
     clock, so host-core contention between shards never enters the
     measurement."""
     from repro.core.fabric import simulator as sim
     from repro.launch.mesh import make_sweep_mesh
 
-    meter = _compile_meter()
-    if args.cache_dir:
-        sim.ensure_compile_cache(args.cache_dir)
+    meter = compile_meter()
+    cache_dir = sim.ensure_compile_cache()
     mesh = None if args.single else make_sweep_mesh()
     first = run_workload(mesh, tiny=args.tiny, dispatch=args.dispatch)
     first_meter = dict(meter)
@@ -327,10 +323,8 @@ def child_main(args) -> dict:
     out["cache_hits"] = first_meter["cache_hits"]
     out["cache_misses"] = first_meter["cache_misses"]
     out["trace_counts"] = dict(sim.TRACE_COUNTS)
-    out["cache_dir"] = args.cache_dir or ""
-    out["cache_entries"] = (len(os.listdir(args.cache_dir))
-                            if args.cache_dir
-                            and os.path.isdir(args.cache_dir) else 0)
+    out["cache_dir"] = cache_dir
+    out["cache_entries"] = len(os.listdir(cache_dir))
     return out
 
 
@@ -341,17 +335,18 @@ def child_main(args) -> dict:
 
 def _spawn_child(*, host_devices, cache_dir, out_path, tiny, dispatch,
                  single=False):
-    cmd = [sys.executable, "-m", "repro.launch.sweep", "--child",
+    cmd = [sys.executable, "-m", "repro.launch.sweep",
            "--out", out_path, "--dispatch", dispatch]
     if single:
         cmd.append("--single")
     if host_devices and not single:
         cmd += ["--host-devices", str(host_devices)]
-    if cache_dir:
-        cmd += ["--cache-dir", cache_dir]
     if tiny:
         cmd.append("--tiny")
-    env = dict(os.environ)
+    # a CPU rehearsal on forced host devices: the child never contends
+    # for an accelerator, and its cache is the directory it is handed
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=cache_dir)
     env.setdefault("PYTHONPATH",
                    os.path.join(os.path.dirname(__file__), "..", ".."))
     r = subprocess.run(cmd, env=env, capture_output=True, text=True,
@@ -363,23 +358,25 @@ def _spawn_child(*, host_devices, cache_dir, out_path, tiny, dispatch,
         return json.load(f)
 
 
-def run_smoke(host_devices: int = 8, *, tiny: bool = False,
-              dispatch: str = "devices", workdir=None) -> dict:
-    """The acceptance harness (CI + engine_bench --sharded): fresh
+def run_smoke(host_devices: int, *, workdir: str, tiny: bool = False,
+              dispatch: str = "devices") -> dict:
+    """The sharded-launch rehearsal on CPU virtual devices: fresh
     children run the same workload (1) on a single device, (2) sharded
-    cold (empty persistent cache), (3) sharded warm (same cache dir).
-    Asserts the sharded results are bit-identical to the single-device
-    run and that the warm relaunch cuts compile time."""
-    tmp = workdir or tempfile.mkdtemp(prefix="repro_sweep_smoke_")
-    cache_dir = os.path.join(tmp, "xla_cache")
-    single = _spawn_child(host_devices=0, cache_dir=None,
-                          out_path=os.path.join(tmp, "single.json"),
+    cold (empty persistent cache under ``workdir``), (3) sharded warm
+    (same cache dir). Asserts the sharded results are bit-identical to
+    the single-device run and that the warm relaunch cuts compile time.
+    ``workdir`` must be a fresh directory: the cold child needs an empty
+    cache."""
+    cache_dir = os.path.join(workdir, "xla_cache")
+    single = _spawn_child(host_devices=0,
+                          cache_dir=os.path.join(workdir, "xla_cache_single"),
+                          out_path=os.path.join(workdir, "single.json"),
                           tiny=tiny, dispatch=dispatch, single=True)
     cold = _spawn_child(host_devices=host_devices, cache_dir=cache_dir,
-                        out_path=os.path.join(tmp, "cold.json"),
+                        out_path=os.path.join(workdir, "cold.json"),
                         tiny=tiny, dispatch=dispatch)
     warm = _spawn_child(host_devices=host_devices, cache_dir=cache_dir,
-                        out_path=os.path.join(tmp, "warm.json"),
+                        out_path=os.path.join(workdir, "warm.json"),
                         tiny=tiny, dispatch=dispatch)
 
     checks = {
@@ -406,6 +403,7 @@ def run_smoke(host_devices: int = 8, *, tiny: bool = False,
                   "launch_first_s", "launch_second_s", "compile_s",
                   "compile_saved_s", "cache_hits", "cache_misses")
     report = {
+        "devices": "cpu virtual devices",
         "host_devices": host_devices,
         "tiny": tiny,
         "dispatch": dispatch,
@@ -421,14 +419,11 @@ def run_smoke(host_devices: int = 8, *, tiny: bool = False,
 
 
 def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("--child", action="store_true",
-                    help="run one measured workload process")
-    ap.add_argument("--smoke", action="store_true",
-                    help="orchestrate single/cold/warm children and "
-                         "assert bit-identity + cache compile cut")
+    ap = argparse.ArgumentParser(
+        description="one measured sweep workload process (run_smoke's "
+                    "child)")
     ap.add_argument("--single", action="store_true",
-                    help="(child) run the plain single-device path")
+                    help="run the plain single-device path")
     ap.add_argument("--host-devices", type=int, default=8,
                     help="forced CPU host device count for sharded runs")
     ap.add_argument("--dispatch", default="devices",
@@ -436,44 +431,19 @@ def main(argv=None) -> int:
                     help="sharded execution mode (devices = bit-exact "
                          "per-device dispatch; shard_map = one "
                          "partitioned jit, ulp-close on multi-device)")
-    ap.add_argument("--cache-dir", default=None,
-                    help="persistent XLA compile cache directory")
     ap.add_argument("--tiny", action="store_true",
                     help="shrunken workload (tier-1 subprocess test)")
-    ap.add_argument("--out", default=None, help="write the JSON report")
+    ap.add_argument("--out", required=True, help="write the JSON report")
     args = ap.parse_args(argv)
 
-    if args.child:
-        if args.host_devices and not args.single:
-            # must happen before the jax backend initializes
-            from repro.jax_compat import force_host_device_count
+    if args.host_devices and not args.single:
+        # must happen before the jax backend initializes
+        from repro.jax_compat import force_host_device_count
 
-            force_host_device_count(args.host_devices)
-        report = child_main(args)
-    elif args.smoke:
-        report = run_smoke(args.host_devices, tiny=args.tiny,
-                           dispatch=args.dispatch)
-        ok = report["ok"]
-        summary = {k: report[k] for k in
-                   ("single", "sharded_cold", "sharded_warm", "checks")}
-        print(json.dumps(summary, indent=1))
-        if not ok:
-            print("sweep smoke FAILED", file=sys.stderr)
-            return 1
-        print("sweep smoke OK: sharded launch bit-identical to "
-              "single-device; persistent cache cut compile "
-              f"{report['sharded_cold']['compile_s']}s -> "
-              f"{report['sharded_warm']['compile_s']}s")
-    else:
-        print("choose --child or --smoke", file=sys.stderr)
-        return 2
-
-    if args.out:
-        slim = {k: v for k, v in report.items()
-                if k not in ("results_scale", "runs_panel")} \
-            if args.smoke else report
-        with open(args.out, "w") as f:
-            json.dump(slim, f, indent=1, default=repr)
+        force_host_device_count(args.host_devices)
+    report = child_main(args)
+    with open(args.out, "w") as f:
+        json.dump(report, f, indent=1, default=repr)
     return 0
 
 

@@ -35,8 +35,8 @@ Two candidate tiers per query:
 Budget exhaustion returns best-so-far (``finish_reason="budget"``);
 a drained grid or converged agent returns ``"drained"``. Multi-device
 meshes plug in via ``launch.sweep.whatif_launcher`` (lane sharding);
-``cache_dir`` promotes the persistent XLA compile cache so a restarted
-service skips compilation.
+the engine's persistent compile cache (simulator.ensure_compile_cache)
+lets a restarted service skip compilation.
 """
 from __future__ import annotations
 
@@ -227,11 +227,7 @@ class WhatIfServer:
     def __init__(self, *, max_batch: int = 4, n_iters: int = 12,
                  warmup: int = 3, max_steps: int = 200_000,
                  chunk: int = 2048, stride: int = 8, mesh=None,
-                 launcher=None, cache_dir: Optional[str] = None):
-        if cache_dir:
-            from repro.core.fabric import simulator as sim
-
-            sim.ensure_compile_cache(cache_dir)
+                 launcher=None):
         self.max_batch = int(max_batch)
         self.run_kw = dict(n_iters=n_iters, warmup=warmup,
                            max_steps=max_steps, chunk=chunk, stride=stride,

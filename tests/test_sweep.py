@@ -2,17 +2,17 @@
 
 In-process tests use a 1-device mesh (the tier-1 suite must not force a
 host device count — conftest.py); the multi-device bit-identity and
-warm-cache properties are exercised through the launcher's own subprocess
-smoke (``--smoke --host-devices 2 --tiny``), which forces devices in
-fresh children.
+warm-cache properties are exercised through the launcher's own CPU
+rehearsal (``run_smoke(2, tiny=True)``), which forces devices in fresh
+children.
 """
-import json
 import os
 import subprocess
 import sys
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import bench, congestion as cong
 from repro.core.fabric import simulator as sim_lib, systems
@@ -137,15 +137,55 @@ def test_run_candidates_launcher_parity():
         assert ra.aggr_bytes == rb.aggr_bytes
 
 
-def test_compile_cache_env_resolution(tmp_path, monkeypatch):
-    """ensure_compile_cache: explicit dir wins, env var is the fallback,
-    and the first successful activation sticks (idempotent)."""
+@pytest.fixture
+def fresh_compile_cache(monkeypatch):
+    """Let a test arm the cache anew; the process's jax config comes back
+    as it was afterwards."""
+    import jax
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    before = {k: getattr(jax.config, k) for k in keys}
     monkeypatch.setattr(sim_lib, "_COMPILE_CACHE_DIR", None)
-    monkeypatch.setenv(sim_lib.COMPILE_CACHE_ENV, str(tmp_path / "env"))
+    yield
+    for k, v in before.items():
+        jax.config.update(k, v)
+
+
+def test_compile_cache_env_resolution(tmp_path, monkeypatch,
+                                      fresh_compile_cache):
+    """ensure_compile_cache: $JAX_COMPILATION_CACHE_DIR wins, the fixed
+    path in the checkout is the fallback, and the first activation
+    sticks (idempotent)."""
+    env_dir = str(tmp_path / "env")
+    monkeypatch.setenv(sim_lib.COMPILE_CACHE_ENV, env_dir)
     active = sim_lib.ensure_compile_cache()
-    assert active == str(tmp_path / "env") and os.path.isdir(active)
-    # already active: a different request is a no-op, not a re-point
-    assert sim_lib.ensure_compile_cache(str(tmp_path / "other")) == active
+    assert active == env_dir and os.path.isdir(active)
+    # already active: a later change of the variable is no re-point
+    monkeypatch.setenv(sim_lib.COMPILE_CACHE_ENV, str(tmp_path / "other"))
+    assert sim_lib.ensure_compile_cache() == active
+
+    monkeypatch.setattr(sim_lib, "_COMPILE_CACHE_DIR", None)
+    monkeypatch.delenv(sim_lib.COMPILE_CACHE_ENV)
+    fixed = sim_lib.ensure_compile_cache()
+    assert fixed == sim_lib.DEFAULT_COMPILE_CACHE_DIR
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert os.path.dirname(fixed) == checkout and os.path.isdir(fixed)
+
+
+def test_compile_cache_env_never_overridden(tmp_path, monkeypatch,
+                                            fresh_compile_cache):
+    """With $JAX_COMPILATION_CACHE_DIR set, no directory in the code
+    (the dry run's old artifact path, the checkout default) is where
+    jax is pointed."""
+    import jax
+
+    want = str(tmp_path / "outside")
+    monkeypatch.setenv(sim_lib.COMPILE_CACHE_ENV, want)
+    assert sim_lib.ensure_compile_cache(min_compile_secs=10.0) == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert want != sim_lib.DEFAULT_COMPILE_CACHE_DIR
 
 
 def test_force_host_device_count_appends(monkeypatch):
@@ -178,18 +218,13 @@ def test_dryrun_import_preserves_user_xla_flags(tmp_path):
 
 def test_sweep_smoke_two_devices(tmp_path):
     """The acceptance harness end-to-end (subprocess children force 2
-    host devices): sharded launch bit-identical to single-device, cache
-    populated, warm relaunch cheaper than cold."""
-    out = tmp_path / "smoke.json"
-    r = subprocess.run(
-        [sys.executable, "-m", "repro.launch.sweep", "--smoke",
-         "--host-devices", "2", "--tiny", "--out", str(out)],
-        env=dict(os.environ, PYTHONPATH="src"), capture_output=True,
-        text=True, cwd=os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), timeout=540)
-    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-3000:]
-    report = json.loads(out.read_text())
+    CPU host devices): sharded launch bit-identical to single-device,
+    cache populated under the test's own directory, warm relaunch
+    cheaper than cold."""
+    report = sweep.run_smoke(2, workdir=str(tmp_path), tiny=True)
     assert report["ok"], report["checks"]
+    assert report["devices"] == "cpu virtual devices"
+    assert os.listdir(tmp_path / "xla_cache")
     assert report["checks"]["bit_identical_scale"]
     assert report["checks"]["bit_identical_panel"]
     assert report["sharded_cold"]["n_devices"] == 2
