@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import congestion as cong
+from repro.core import spans
 from repro.core import traffic
 from repro.core.fabric.simulator import (TDONE_SLOTS, FabricGeometry,
                                          SimParams, _drop_warmup,
@@ -470,15 +471,22 @@ def run_grid(system: Union[SystemPreset, Sequence[ScaleCell]], n_nodes: int,
     check_iter_budget(n_iters)
     # any node-capped lane arms the intra-node stage for the whole case
     # (inert at inf)
-    case = build_case(system, n_nodes, victim_coll, aggr_coll,
-                      phased=phased, jobs=jobs,
-                      intra_node=any(p.node_cap_frac > 0 for p in profiles))
-    dts, params = grid_params(case, sizes, profiles, dt)
+    with spans.span(spans.BUILD_CASE):
+        case = build_case(system, n_nodes, victim_coll, aggr_coll,
+                          phased=phased, jobs=jobs,
+                          intra_node=any(p.node_cap_frac > 0
+                                         for p in profiles))
+    with spans.span(spans.GRID_PARAMS):
+        dts, params = grid_params(case, sizes, profiles, dt)
     max_chunks = -(-max_steps // chunk)
-    out = run_cells(case.geom, params, jnp.asarray(n_iters, jnp.int32),
-                    chunk=chunk, max_chunks=max_chunks, stride=trace_stride)
-    return _grid_results(case, out, sizes, profiles, dts, n_iters=n_iters,
-                         warmup=warmup, chunk=chunk, stride=trace_stride)
+    with spans.span(spans.DISPATCH):
+        out = run_cells(case.geom, params, jnp.asarray(n_iters, jnp.int32),
+                        chunk=chunk, max_chunks=max_chunks,
+                        stride=trace_stride)
+    with spans.span(spans.MARSHAL):
+        return _grid_results(case, out, sizes, profiles, dts,
+                             n_iters=n_iters, warmup=warmup, chunk=chunk,
+                             stride=trace_stride)
 
 
 # --------------------------------------------------------------------------
@@ -523,13 +531,15 @@ class PendingGrid:
     stride: int
 
     def results(self) -> List[BenchResult]:
-        return [r for k, case in enumerate(self.cases)
-                for r in _grid_results(case, self.out, self.sizes,
-                                       self.profiles, self.all_dts[k],
-                                       n_iters=self.n_iters,
-                                       warmup=self.warmup, chunk=self.chunk,
-                                       stride=self.stride,
-                                       cell_prefix=(k,))]
+        with spans.span(spans.MARSHAL):
+            return [r for k, case in enumerate(self.cases)
+                    for r in _grid_results(case, self.out, self.sizes,
+                                           self.profiles, self.all_dts[k],
+                                           n_iters=self.n_iters,
+                                           warmup=self.warmup,
+                                           chunk=self.chunk,
+                                           stride=self.stride,
+                                           cell_prefix=(k,))]
 
 
 def launch_scale_grid(cells: Sequence[ScaleCell], victim_coll: str,
@@ -548,30 +558,37 @@ def launch_scale_grid(cells: Sequence[ScaleCell], victim_coll: str,
     launcher = _resolve_launcher(mesh, launcher)
     with_ft = cong.needs_fault_table(profiles)
     intra = any(p.node_cap_frac > 0 for p in profiles)
-    cases = []
-    for sysname, n in cells:
-        sysp = get_system(sysname) if isinstance(sysname, str) else sysname
-        cases.append(build_case(sysp, int(n), victim_coll, aggr_coll,
-                                phased=phased, jobs=jobs, intra_node=intra))
+    with spans.span(spans.BUILD_CASE):
+        cases = []
+        for sysname, n in cells:
+            sysp = get_system(sysname) if isinstance(sysname, str) \
+                else sysname
+            cases.append(build_case(sysp, int(n), victim_coll, aggr_coll,
+                                    phased=phased, jobs=jobs,
+                                    intra_node=intra))
+        if cases:
+            dims, stacked = bucket_stack([case.geom for case in cases])
     sizes, profiles = tuple(sizes), tuple(profiles)
     if not cases:
         return PendingGrid([], {}, sizes, profiles, [], n_iters, warmup,
                            chunk, trace_stride)
 
-    dims, stacked = bucket_stack([case.geom for case in cases])
-    all_dts = [_cell_dts(case, sizes, len(profiles), dt, case.lat())
-               for case in cases]
-    sub_cells = [(float(v), prof) for v in sizes
-                 for prof in [cong.no_congestion()] + list(profiles)]
-    params = stack_params([
-        stack_params([case.cell_params(v, prof, d, n_flows=dims.n_flows,
-                                       with_fault_table=with_ft)
-                      for (v, prof), d in zip(sub_cells, all_dts[k])])
-        for k, case in enumerate(cases)])
+    with spans.span(spans.GRID_PARAMS):
+        all_dts = [_cell_dts(case, sizes, len(profiles), dt, case.lat())
+                   for case in cases]
+        sub_cells = [(float(v), prof) for v in sizes
+                     for prof in [cong.no_congestion()] + list(profiles)]
+        params = stack_params([
+            stack_params([case.cell_params(v, prof, d,
+                                           n_flows=dims.n_flows,
+                                           with_fault_table=with_ft)
+                          for (v, prof), d in zip(sub_cells, all_dts[k])])
+            for k, case in enumerate(cases)])
     run = launcher if launcher is not None else run_cells_hetero
-    out = run(stacked, params, jnp.asarray(n_iters, jnp.int32),
-              chunk=chunk, max_chunks=-(-max_steps // chunk),
-              stride=trace_stride)
+    with spans.span(spans.DISPATCH):
+        out = run(stacked, params, jnp.asarray(n_iters, jnp.int32),
+                  chunk=chunk, max_chunks=-(-max_steps // chunk),
+                  stride=trace_stride)
     return PendingGrid(cases, out, sizes, profiles, all_dts, n_iters,
                        warmup, chunk, trace_stride)
 

@@ -111,6 +111,18 @@ def resolve_step_backend(backend: Optional[str] = None) -> str:
 TRACE_COUNTS: collections.Counter = collections.Counter()
 
 
+# Named scopes of the engine (DESIGN.md §18). Every op of a compiled
+# engine entry lies under ENGINE_SCOPE, and every op of a step under
+# STEP_SCOPE and one of its sections, so a device trace attributes its
+# time by section through each op's ``op_name``. Scopes are metadata: the
+# compiled program and its results are those of an unscoped build. New
+# code in the step goes under an existing section or a new listed one.
+ENGINE_SCOPE = "engine_loop"
+STEP_SCOPE = "fabric_step"
+STEP_SECTIONS = ("envelope", "route", "step_core", "signals", "cc",
+                 "progress", "queue_delay", "metrics_carry")
+
+
 def trace_count(entry: str = None) -> int:
     """Total traces of one engine entry (or all entries)."""
     if entry is None:
@@ -762,237 +774,264 @@ def step_debug(geom: FabricGeometry, p: SimParams, state,
 
 def _step_impl(geom: FabricGeometry, p: SimParams, state, with_aux: bool,
                backend: str = "ref"):
+    with jax.named_scope(STEP_SCOPE):
+        return _step_sections(geom, p, state, with_aux, backend)
+
+
+def _step_sections(geom: FabricGeometry, p: SimParams, state,
+                   with_aux: bool, backend: str):
     dt = p.dt
-    # aggressor envelope: traceable function of sim time (no host callback)
-    env_t = envelope_at(p.env, state["t"])
-    # phase membership: a flow transmits only while its job's phase
-    # counter sits on the flow's phase (and its phase bytes remain);
-    # negative phase id = wildcard, member of every phase
-    # (traffic.WILDCARD_PHASE — uniform ring schedules)
-    in_phase = (geom.flow_phase == state["ph"][geom.flow_job]) \
-        | (geom.flow_phase < 0)
-    # flow_start gates stochastic arrivals (workload.py); the scalar 0.0
-    # default keeps the predicate all-true — legacy runs are bit-identical
-    alive = (state["rem"] > 0) & in_phase & (state["t"] >= p.flow_start)
-    active = (geom.is_victim | (env_t > 0)) & alive
-    gate = jnp.where(geom.is_victim, 1.0, env_t) * alive
-    inject = state["c"] * gate
-    # (The NIC injection limit now lives in the fused step core below —
-    # it has no data dependence on routing, so applying it after the
-    # path choice is bit-identical.)
+    with jax.named_scope("envelope"):
+        # aggressor envelope: traceable function of sim time (no host
+        # callback)
+        env_t = envelope_at(p.env, state["t"])
+        # phase membership: a flow transmits only while its job's phase
+        # counter sits on the flow's phase (and its phase bytes remain);
+        # negative phase id = wildcard, member of every phase
+        # (traffic.WILDCARD_PHASE — uniform ring schedules)
+        in_phase = (geom.flow_phase == state["ph"][geom.flow_job]) \
+            | (geom.flow_phase < 0)
+        # flow_start gates stochastic arrivals (workload.py); the scalar
+        # 0.0 default keeps the predicate all-true — legacy runs are
+        # bit-identical
+        alive = (state["rem"] > 0) & in_phase & (state["t"] >= p.flow_start)
+        active = (geom.is_victim | (env_t > 0)) & alive
+        gate = jnp.where(geom.is_victim, 1.0, env_t) * alive
+        inject = state["c"] * gate
+        # (The NIC injection limit now lives in the fused step core below
+        # — it has no data dependence on routing, so applying it after
+        # the path choice is bit-identical.)
 
-    # ---- link-fault engine (envelopes.fault_scale_at, DESIGN.md §16) ----
-    # Per-link capacity scale at sim time t, folded into the caps operand
-    # OUTSIDE the kernel launch so both step-core backends consume
-    # already-scaled capacities and the fused kernel body is untouched.
-    # p.fault is None on the legacy path (absent pytree leaf — the trace
-    # is byte-identical to a build without the feature); the all-``none``
-    # table lowers to an exact 1.0 scale, and caps * 1.0 is bit-exact for
-    # finite positive f32 capacities (the inertness contract the
-    # fault-table tests pin on every state leaf).
-    caps_lk = geom.caps_finite
-    if p.fault is not None:
-        caps_lk = caps_lk * fault_scale_at(p.fault, geom.link_group,
-                                           state["t"],
-                                           link_sw_group=geom.link_sw_group)
+        # ---- link-fault engine (envelopes.fault_scale_at, DESIGN.md
+        # §16) ----
+        # Per-link capacity scale at sim time t, folded into the caps
+        # operand OUTSIDE the kernel launch so both step-core backends
+        # consume already-scaled capacities and the fused kernel body is
+        # untouched. p.fault is None on the legacy path (absent pytree
+        # leaf — the trace is byte-identical to a build without the
+        # feature); the all-``none`` table lowers to an exact 1.0 scale,
+        # and caps * 1.0 is bit-exact for finite positive f32 capacities
+        # (the inertness contract the fault-table tests pin on every
+        # state leaf).
+        caps_lk = geom.caps_finite
+        if p.fault is not None:
+            caps_lk = caps_lk * fault_scale_at(
+                p.fault, geom.link_group, state["t"],
+                link_sw_group=geom.link_sw_group)
 
-    # ---- optional intra-node stage (NVLink/PCIe ahead of the NIC) ----
-    # Flows sharing a source node proportionally split the node's
-    # internal bandwidth BEFORE the NIC limit — the same fluid share rule
-    # the core applies per NIC, one stage earlier (Tarraga-Moreno et al.;
-    # DESIGN.md §16). The flag is geometry meta (static), so flag-off
-    # traces carry none of these ops; node_cap == +inf makes the stage an
-    # exact no-op (scale 1.0), letting stage-on buckets host stage-off
-    # cells bit-identically.
-    if geom.intra_node:
-        nload = jnp.zeros((geom.n_src,), jnp.float32) \
-            .at[geom.src_id].add(inject)
-        ncap = p.node_cap + jnp.zeros((geom.n_src,), jnp.float32)
-        nscale = jnp.minimum(1.0, ncap / jnp.maximum(nload, 1.0))
-        inject = inject * nscale[geom.src_id]
+        # ---- optional intra-node stage (NVLink/PCIe ahead of the NIC)
+        # Flows sharing a source node proportionally split the node's
+        # internal bandwidth BEFORE the NIC limit — the same fluid share
+        # rule the core applies per NIC, one stage earlier
+        # (Tarraga-Moreno et al.; DESIGN.md §16). The flag is geometry
+        # meta (static), so flag-off traces carry none of these ops;
+        # node_cap == +inf makes the stage an exact no-op (scale 1.0),
+        # letting stage-on buckets host stage-off cells bit-identically.
+        if geom.intra_node:
+            nload = jnp.zeros((geom.n_src,), jnp.float32) \
+                .at[geom.src_id].add(inject)
+            ncap = p.node_cap + jnp.zeros((geom.n_src,), jnp.float32)
+            nscale = jnp.minimum(1.0, ncap / jnp.maximum(nload, 1.0))
+            inject = inject * nscale[geom.src_id]
 
-    # ---- routing: traced per-cell policy (lax.switch over p.policy) ----
-    # Static tables (fixed / ecmp / nslb) read precomputed host-side
-    # assignments; dynamic policies score candidates by queue occupancy.
-    # Under vmap the switch lowers to a select, so one compile serves a
-    # grid mixing every policy. The candidate scores are hoisted out of
-    # the branches and computed ONCE — the dominant engine entries are
-    # batched (run_cells/_hetero evaluate every branch anyway), so
-    # sharing the (F, K, H) occupancy gather halves its per-step cost.
-    # ``occ`` is shared with the backpressure stage of the core.
-    occ = state["q"] / p.qmax_bytes
-    score = jnp.max(occ[geom.paths], axis=2) \
-        + 0.05 * geom.path_len / jnp.maximum(geom.path_len[:, :1], 1)
-    score = jnp.where(np.arange(geom.paths.shape[1])[None, :]
-                      < geom.n_paths[:, None], score, jnp.inf)
-    best = jnp.argmin(score, axis=1)
-    best_score = jnp.min(score, axis=1)
+    with jax.named_scope("route"):
+        # Traced per-cell policy (lax.switch over p.policy). Static
+        # tables (fixed / ecmp / nslb) read precomputed host-side
+        # assignments; dynamic policies score candidates by queue
+        # occupancy. Under vmap the switch lowers to a select, so one
+        # compile serves a grid mixing every policy. The candidate scores
+        # are hoisted out of the branches and computed ONCE — the
+        # dominant engine entries are batched (run_cells/_hetero evaluate
+        # every branch anyway), so sharing the (F, K, H) occupancy gather
+        # halves its per-step cost. ``occ`` is shared with the
+        # backpressure stage of the core.
+        occ = state["q"] / p.qmax_bytes
+        score = jnp.max(occ[geom.paths], axis=2) \
+            + 0.05 * geom.path_len / jnp.maximum(geom.path_len[:, :1], 1)
+        score = jnp.where(np.arange(geom.paths.shape[1])[None, :]
+                          < geom.n_paths[:, None], score, jnp.inf)
+        best = jnp.argmin(score, axis=1)
+        best_score = jnp.min(score, axis=1)
 
-    def _hysteresis(anchor):
-        # Production AR does NOT send every flow to the globally least-
-        # loaded port (that herds and oscillates): a flow leaves its
-        # anchor path only when its occupancy is clearly worse than the
-        # best alternative.
-        a_score = jnp.take_along_axis(score, anchor[:, None], 1)[:, 0]
-        return jnp.where(a_score > best_score + 0.10, best, anchor)
+        def _hysteresis(anchor):
+            # Production AR does NOT send every flow to the globally
+            # least-loaded port (that herds and oscillates): a flow
+            # leaves its anchor path only when its occupancy is clearly
+            # worse than the best alternative.
+            a_score = jnp.take_along_axis(score, anchor[:, None], 1)[:, 0]
+            return jnp.where(a_score > best_score + 0.10, best, anchor)
 
-    def _route_adaptive(_):
-        # anchored on the sprayed home path, re-evaluated every step
-        return _hysteresis(geom.spray_choice), state["rc"]
+        def _route_adaptive(_):
+            # anchored on the sprayed home path, re-evaluated every step
+            return _hysteresis(geom.spray_choice), state["rc"]
 
-    def _route_flowlet(_):
-        # flowlet re-pathing: keep the current path while the flow
-        # transmits; once its idle gap exceeds the traced threshold the
-        # next burst re-evaluates — anchored on the CURRENT path with
-        # the same hysteresis as adaptive (all-idle flows re-picking a
-        # global argmin would herd onto one uplink), but only at flowlet
-        # boundaries (idle resets on activity below, so a live flow
-        # never re-orders mid-burst).
-        rc = jnp.where(state["idle"] >= p.flowlet_gap_s,
-                       _hysteresis(state["rc"]), state["rc"])
-        return rc, rc
+        def _route_flowlet(_):
+            # flowlet re-pathing: keep the current path while the flow
+            # transmits; once its idle gap exceeds the traced threshold
+            # the next burst re-evaluates — anchored on the CURRENT path
+            # with the same hysteresis as adaptive (all-idle flows
+            # re-picking a global argmin would herd onto one uplink), but
+            # only at flowlet boundaries (idle resets on activity below,
+            # so a live flow never re-orders mid-burst).
+            rc = jnp.where(state["idle"] >= p.flowlet_gap_s,
+                           _hysteresis(state["rc"]), state["rc"])
+            return rc, rc
 
-    route_branches = [None] * 5
-    route_branches[POLICY_FIXED] = lambda _: (geom.fixed_choice, state["rc"])
-    route_branches[POLICY_ECMP] = lambda _: (geom.ecmp_choice, state["rc"])
-    route_branches[POLICY_NSLB] = lambda _: (geom.nslb_choice, state["rc"])
-    route_branches[POLICY_ADAPTIVE] = _route_adaptive
-    route_branches[POLICY_FLOWLET] = _route_flowlet
-    choice, rc_new = jax.lax.switch(p.policy, route_branches, None)
-    idle_new = jnp.where(active, 0.0, state["idle"] + dt)
-    plinks = jnp.take_along_axis(
-        geom.paths, choice[:, None, None], axis=1)[:, 0]  # (F, H)
-    valid = plinks < geom.L
+        route_branches = [None] * 5
+        route_branches[POLICY_FIXED] = \
+            lambda _: (geom.fixed_choice, state["rc"])
+        route_branches[POLICY_ECMP] = \
+            lambda _: (geom.ecmp_choice, state["rc"])
+        route_branches[POLICY_NSLB] = \
+            lambda _: (geom.nslb_choice, state["rc"])
+        route_branches[POLICY_ADAPTIVE] = _route_adaptive
+        route_branches[POLICY_FLOWLET] = _route_flowlet
+        choice, rc_new = jax.lax.switch(p.policy, route_branches, None)
+        idle_new = jnp.where(active, 0.0, state["idle"] + dt)
+        plinks = jnp.take_along_axis(
+            geom.paths, choice[:, None, None], axis=1)[:, 0]  # (F, H)
+        valid = plinks < geom.L
 
-    # ---- fused step core (NIC limit, backpressure stall, staged
-    # propagation, queue update) ----
-    # The memory-bound scatter/segment-sum core lives in repro.kernels:
-    # kernels/ref.py holds the original lax code verbatim (the oracle and
-    # CPU default), kernels/fabric_step.py the fused Pallas kernel. The
-    # physics — why backpressure is share-weighted, why propagation is
-    # feed-forward FIFO fluid sharing — is documented on the oracle and
-    # in DESIGN.md §13.
-    if backend == "pallas":
-        core = kernel_ops.fabric_step_core(
+    with jax.named_scope("step_core"):
+        # Fused step core (NIC limit, backpressure stall, staged
+        # propagation, queue update). The memory-bound
+        # scatter/segment-sum core lives in repro.kernels:
+        # kernels/ref.py holds the original lax code verbatim (the oracle
+        # and CPU default), kernels/fabric_step.py the fused Pallas
+        # kernel. The physics — why backpressure is share-weighted, why
+        # propagation is feed-forward FIFO fluid sharing — is documented
+        # on the oracle and in DESIGN.md §13.
+        core_fn = kernel_ops.fabric_step_core if backend == "pallas" \
+            else kernel_ref.fabric_step_core
+        core = core_fn(
             plinks, inject, geom.src_id, p.host_caps, state["q"], occ,
             caps_lk, geom.src_sw, geom.dst_sw, dt, p.qmax_bytes,
             p.hol_factor, p.hol_start, p.burst_jitter,
             n_src=geom.n_src, n_sw=geom.n_sw, with_aux=with_aux)
-    else:
-        core = kernel_ref.fabric_step_core(
-            plinks, inject, geom.src_id, p.host_caps, state["q"], occ,
-            caps_lk, geom.src_sw, geom.dst_sw, dt, p.qmax_bytes,
-            p.hol_factor, p.hol_start, p.burst_jitter,
-            n_src=geom.n_src, n_sw=geom.n_sw, with_aux=with_aux)
-    inject = core["inject"]  # NIC-scaled
-    a = core["achieved"]  # achieved end-to-end rate
-    arrival = core["arrival"]
-    caps_eff = core["caps_eff"]
-    served_stage_max = core["served_stage_max"]
-    q = core["q_new"]
+        inject = core["inject"]  # NIC-scaled
+        a = core["achieved"]  # achieved end-to-end rate
+        arrival = core["arrival"]
+        caps_eff = core["caps_eff"]
+        served_stage_max = core["served_stage_max"]
+        q = core["q_new"]
 
-    # ---- signals ----
-    # AI-ECN: threshold tracks a fraction of the observed queue so
-    # marking strength is proportional, not bang-bang. thresh_adapt == 0
-    # keeps the static kmin threshold.
-    adapted = jnp.clip(0.9 * state["thresh"] + 0.1 * (0.5 * q + p.kmin
-                                                      * p.qmax_bytes),
-                       0.05 * p.qmax_bytes, p.kmax * p.qmax_bytes)
-    thresh = jnp.where(p.thresh_adapt > 0, adapted, state["thresh"])
-    over_thresh = q > thresh
-    fmark = jnp.any(over_thresh[plinks] & valid, axis=1)
-    # proportional mark strength (ai_ecn) in [0, 1]
-    strength_l = jnp.clip((q - thresh)
-                          / (p.kmax * p.qmax_bytes - thresh + 1.0),
-                          0.0, 1.0)
-    fstrength = jnp.max(jnp.where(valid, strength_l[plinks], 0.0), axis=1)
+    with jax.named_scope("signals"):
+        # AI-ECN: threshold tracks a fraction of the observed queue so
+        # marking strength is proportional, not bang-bang.
+        # thresh_adapt == 0 keeps the static kmin threshold.
+        adapted = jnp.clip(0.9 * state["thresh"]
+                           + 0.1 * (0.5 * q + p.kmin * p.qmax_bytes),
+                           0.05 * p.qmax_bytes, p.kmax * p.qmax_bytes)
+        thresh = jnp.where(p.thresh_adapt > 0, adapted, state["thresh"])
+        over_thresh = q > thresh
+        fmark = jnp.any(over_thresh[plinks] & valid, axis=1)
+        # proportional mark strength (ai_ecn) in [0, 1]
+        strength_l = jnp.clip((q - thresh)
+                              / (p.kmax * p.qmax_bytes - thresh + 1.0),
+                              0.0, 1.0)
+        fstrength = jnp.max(jnp.where(valid, strength_l[plinks], 0.0),
+                            axis=1)
 
-    # ---- CC update (lax.switch over fabric kind) ----
-    can_dec = state["last_dec"] >= p.cc_interval_s
-    c, dec = _cc_update(p, state["c"], a, fmark, fstrength, can_dec)
-    # CC state only evolves for flows that are actually transmitting —
-    # an idle flow (finished its iteration early, or paused aggressor)
-    # keeps its rate limit.
-    c = jnp.where(active, c, state["c"])
-    dec = dec & active
-    c = jnp.clip(c, p.min_rate_frac * p.host_caps, p.host_caps)
-    last_dec = jnp.where(dec, 0.0, state["last_dec"] + dt)
+    with jax.named_scope("cc"):
+        # lax.switch over fabric kind
+        can_dec = state["last_dec"] >= p.cc_interval_s
+        c, dec = _cc_update(p, state["c"], a, fmark, fstrength, can_dec)
+        # CC state only evolves for flows that are actually transmitting
+        # — an idle flow (finished its iteration early, or paused
+        # aggressor) keeps its rate limit.
+        c = jnp.where(active, c, state["c"])
+        dec = dec & active
+        c = jnp.clip(c, p.min_rate_frac * p.host_caps, p.host_caps)
+        last_dec = jnp.where(dec, 0.0, state["last_dec"] + dt)
 
-    # ---- progress + phase/program bookkeeping ----
-    rem = state["rem"] - a * dt
-    # completion event: the flow was eligible and its budget crossed zero
-    # this very step (captured before `enter` re-arms rem below)
-    done_now = alive & (rem <= 0)
-    t_new = state["t"] + dt
-    # per-job barrier: a phase completes only when its SLOWEST member
-    # flow has drained (straggler semantics, DESIGN.md §7) ...
-    busy = jnp.zeros((geom.n_jobs,), jnp.int32).at[geom.flow_job].max(
-        (in_phase & (rem > 0)).astype(jnp.int32)) > 0
-    # ... then the compute gap of the phase runs before the barrier
-    # releases the next phase (gap == 0 -> advance in the same step,
-    # which is exactly the pre-program iteration semantics)
-    gap = state["gap"] - dt * (~busy)
-    advance = ~busy & (gap <= 0)
-    ph_next = jnp.where(advance,
-                        (state["ph"] + 1) % geom.n_phases, state["ph"])
-    wrap = advance & (state["ph"] + 1 >= geom.n_phases)
-    gap = jnp.where(advance,
-                    jnp.take_along_axis(geom.phase_gap, ph_next[:, None],
-                                        axis=1)[:, 0], gap)
-    # flows of the newly-entered phase reload their byte budget
-    # (wildcard flows re-arm at every phase entry)
-    enter = advance[geom.flow_job] \
-        & ((geom.flow_phase == ph_next[geom.flow_job])
-           | (geom.flow_phase < 0))
-    rem = jnp.where(enter, p.bytes_per_iter, rem)
-    # a job wrapping phase 0 completed one program iteration
-    it = state["it"]
-    slot = jnp.minimum(it, TDONE_SLOTS - 1)
-    onehot = _TDONE_ARANGE[None, :] == slot[:, None]
-    t_done = jnp.where(wrap[:, None] & onehot, t_new, state["t_done"])
-    it = it + wrap.astype(jnp.int32)
-    # synchronization gap between iterations of the primary (measured)
-    # job partially drains queues
-    q = jnp.where(wrap[0], q * p.iter_drain, q)
+    with jax.named_scope("progress"):
+        # progress + phase/program bookkeeping
+        rem = state["rem"] - a * dt
+        # completion event: the flow was eligible and its budget crossed
+        # zero this very step (captured before `enter` re-arms rem below)
+        done_now = alive & (rem <= 0)
+        t_new = state["t"] + dt
+        # per-job barrier: a phase completes only when its SLOWEST member
+        # flow has drained (straggler semantics, DESIGN.md §7) ...
+        busy = jnp.zeros((geom.n_jobs,), jnp.int32).at[geom.flow_job].max(
+            (in_phase & (rem > 0)).astype(jnp.int32)) > 0
+        # ... then the compute gap of the phase runs before the barrier
+        # releases the next phase (gap == 0 -> advance in the same step,
+        # which is exactly the pre-program iteration semantics)
+        gap = state["gap"] - dt * (~busy)
+        advance = ~busy & (gap <= 0)
+        ph_next = jnp.where(advance,
+                            (state["ph"] + 1) % geom.n_phases, state["ph"])
+        wrap = advance & (state["ph"] + 1 >= geom.n_phases)
+        gap = jnp.where(advance,
+                        jnp.take_along_axis(geom.phase_gap,
+                                            ph_next[:, None], axis=1)[:, 0],
+                        gap)
+        # flows of the newly-entered phase reload their byte budget
+        # (wildcard flows re-arm at every phase entry)
+        enter = advance[geom.flow_job] \
+            & ((geom.flow_phase == ph_next[geom.flow_job])
+               | (geom.flow_phase < 0))
+        rem = jnp.where(enter, p.bytes_per_iter, rem)
+        # a job wrapping phase 0 completed one program iteration
+        it = state["it"]
+        slot = jnp.minimum(it, TDONE_SLOTS - 1)
+        onehot = _TDONE_ARANGE[None, :] == slot[:, None]
+        t_done = jnp.where(wrap[:, None] & onehot, t_new, state["t_done"])
+        it = it + wrap.astype(jnp.int32)
+        # synchronization gap between iterations of the primary
+        # (measured) job partially drains queues
+        q = jnp.where(wrap[0], q * p.iter_drain, q)
 
-    # queueing delay experienced by victim flows (seconds) — against the
-    # fault-scaled capacity: a drained-down link serves its queue slower
-    qdel = jnp.max(jnp.where(valid, (q / caps_lk)[plinks], 0.0),
-                   axis=1)
-    mean_qdel = jnp.sum(qdel * geom.is_victim) / jnp.maximum(
-        jnp.sum(geom.is_victim), 1)
-    vict_goodput = jnp.sum(a * geom.is_victim)
+    with jax.named_scope("queue_delay"):
+        # queueing delay experienced by victim flows (seconds) — against
+        # the fault-scaled capacity: a drained-down link serves its queue
+        # slower
+        qdel = jnp.max(jnp.where(valid, (q / caps_lk)[plinks], 0.0),
+                       axis=1)
+        mean_qdel = jnp.sum(qdel * geom.is_victim) / jnp.maximum(
+            jnp.sum(geom.is_victim), 1)
+        vict_goodput = jnp.sum(a * geom.is_victim)
+
+    # the two accumulators, in the order the state lists them
+    with jax.named_scope("progress"):
+        fbytes = state["fbytes"] + a * dt
+    with jax.named_scope("queue_delay"):
+        qd_acc = state["qd_acc"] + mean_qdel * dt
 
     new_state = {"c": c, "rem": rem, "q": q, "arr": arrival,
                  "thresh": thresh, "last_dec": last_dec,
-                 "rc": rc_new, "idle": idle_new,
-                 "fbytes": state["fbytes"] + a * dt,
+                 "rc": rc_new, "idle": idle_new, "fbytes": fbytes,
                  "ph": ph_next, "gap": gap, "it": it, "t_done": t_done,
-                 "qd_acc": state["qd_acc"] + mean_qdel * dt, "t": t_new}
+                 "qd_acc": qd_acc, "t": t_new}
 
     if "h_qd" in state:  # streaming metrics carry (init_state(metrics=True))
         from repro.core import metrics as met
-        # queue delay: every transmitting flow contributes one sample/step
-        w_qd = active.astype(jnp.float32)
-        h_qd = met.hist_add(state["h_qd"], qdel, w_qd, jnp)
-        # completion: an alive flow whose budget crossed zero this step
-        # (done is computed BEFORE the `enter` re-arm overwrote rem)
-        fct = t_new - state["armed_t"]
-        w_done = done_now.astype(jnp.float32)
-        h_fct = met.hist_add(state["h_fct"], fct,
-                             w_done * (p.fct_mask + jnp.zeros_like(fct)),
-                             jnp)
-        # per-tenant slowdown: FCT normalized by the flow's ideal
-        # (uncontended line-rate) drain time, merged Welford-style per job
-        ideal = p.bytes_per_iter / jnp.maximum(p.host_caps, 1.0)
-        slow = fct / jnp.maximum(ideal, 1e-9)
-        wn, wmean, wm2 = met.welford_update(
-            state["wn"], state["wmean"], state["wm2"], slow, w_done,
-            geom.flow_job, geom.n_jobs, jnp)
-        new_state.update({
-            "armed_t": jnp.where(enter, t_new, state["armed_t"]),
-            "h_qd": h_qd, "h_fct": h_fct,
-            "wn": wn, "wmean": wmean, "wm2": wm2})
+        with jax.named_scope("metrics_carry"):
+            # queue delay: every transmitting flow contributes one
+            # sample/step
+            w_qd = active.astype(jnp.float32)
+            h_qd = met.hist_add(state["h_qd"], qdel, w_qd, jnp)
+            # completion: an alive flow whose budget crossed zero this
+            # step (done is computed BEFORE the `enter` re-arm overwrote
+            # rem)
+            fct = t_new - state["armed_t"]
+            w_done = done_now.astype(jnp.float32)
+            h_fct = met.hist_add(state["h_fct"], fct,
+                                 w_done * (p.fct_mask + jnp.zeros_like(fct)),
+                                 jnp)
+            # per-tenant slowdown: FCT normalized by the flow's ideal
+            # (uncontended line-rate) drain time, merged Welford-style
+            # per job
+            ideal = p.bytes_per_iter / jnp.maximum(p.host_caps, 1.0)
+            slow = fct / jnp.maximum(ideal, 1e-9)
+            wn, wmean, wm2 = met.welford_update(
+                state["wn"], state["wmean"], state["wm2"], slow, w_done,
+                geom.flow_job, geom.n_jobs, jnp)
+            new_state.update({
+                "armed_t": jnp.where(enter, t_new, state["armed_t"]),
+                "h_qd": h_qd, "h_fct": h_fct,
+                "wn": wn, "wmean": wmean, "wm2": wm2})
 
     if with_aux:
         aux = {"inject": inject, "achieved": a, "arrival": arrival,
@@ -1016,38 +1055,42 @@ def _run_cell(geom: FabricGeometry, p: SimParams, n_iters,
     buffer — the replay path's peak memory is then O(F + bins) per cell,
     independent of the step budget (no O(T) allocation at all)."""
     assert chunk % stride == 0, (chunk, stride)
-    trace_chunk = chunk // stride
-    state = init_state(geom, p, metrics=metrics)
-    buf = jnp.zeros((max_chunks * trace_chunk if with_trace else 1,),
-                    jnp.float32)
+    # the chunk loop's own ops (initial state, the exit test, the goodput
+    # buffer write) lie under ENGINE_SCOPE; each step under STEP_SCOPE
+    with jax.named_scope(ENGINE_SCOPE):
+        trace_chunk = chunk // stride
+        state = init_state(geom, p, metrics=metrics)
+        buf = jnp.zeros((max_chunks * trace_chunk if with_trace else 1,),
+                        jnp.float32)
 
-    def cond(carry):
-        state, _, k = carry
-        # job 0 is the primary (measured) job; background jobs loop for
-        # as long as it runs and report however many programs they closed
-        return (k < max_chunks) & (state["it"][0] < n_iters)
+        def cond(carry):
+            state, _, k = carry
+            # job 0 is the primary (measured) job; background jobs loop
+            # for as long as it runs and report however many programs
+            # they closed
+            return (k < max_chunks) & (state["it"][0] < n_iters)
 
-    def body(carry):
-        state, buf, k = carry
-        state, gp = jax.lax.scan(
-            lambda s, _: _step_impl(geom, p, s, with_aux=False,
-                                    backend=backend),
-            state, None, length=chunk)
-        if with_trace:
-            buf = jax.lax.dynamic_update_slice(buf, gp[::stride],
-                                               (k * trace_chunk,))
-        return state, buf, k + 1
+        def body(carry):
+            state, buf, k = carry
+            state, gp = jax.lax.scan(
+                lambda s, _: _step_impl(geom, p, s, with_aux=False,
+                                        backend=backend),
+                state, None, length=chunk)
+            if with_trace:
+                buf = jax.lax.dynamic_update_slice(buf, gp[::stride],
+                                                   (k * trace_chunk,))
+            return state, buf, k + 1
 
-    state, buf, k = jax.lax.while_loop(
-        cond, body, (state, buf, jnp.zeros((), jnp.int32)))
-    out = {"t_done": state["t_done"], "it": state["it"],
-           "qd_acc": state["qd_acc"], "t": state["t"],
-           "fbytes": state["fbytes"],
-           "trace": buf, "chunks": k}
-    if metrics:
-        out.update({k2: state[k2]
-                    for k2 in ("h_qd", "h_fct", "wn", "wmean", "wm2")})
-    return out
+        state, buf, k = jax.lax.while_loop(
+            cond, body, (state, buf, jnp.zeros((), jnp.int32)))
+        out = {"t_done": state["t_done"], "it": state["it"],
+               "qd_acc": state["qd_acc"], "t": state["t"],
+               "fbytes": state["fbytes"],
+               "trace": buf, "chunks": k}
+        if metrics:
+            out.update({k2: state[k2]
+                        for k2 in ("h_qd", "h_fct", "wn", "wmean", "wm2")})
+        return out
 
 
 # The public entries resolve the step-core backend EAGERLY (a Python

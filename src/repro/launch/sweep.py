@@ -109,6 +109,7 @@ def dispatch_hetero(geoms, params, n_iters, *, mesh, shard_axis="cell",
     return without blocking."""
     import jax
 
+    from repro.core import spans
     from repro.core.fabric import simulator as sim
 
     if shard_axis not in ("cell", "lane"):
@@ -119,13 +120,14 @@ def dispatch_hetero(geoms, params, n_iters, *, mesh, shard_axis="cell",
     n = int(jax.tree_util.tree_leaves(params)[0].shape[axis])
     outs = []
     for (lo, hi), dev in zip(_shard_bounds(n, len(devices)), devices):
-        g = geoms if axis == 1 else _tree_slice(geoms, lo, hi, 0)
-        outs.append(sim.run_cells_hetero(
-            jax.device_put(g, dev),
-            jax.device_put(_tree_slice(params, lo, hi, axis), dev),
-            jax.device_put(n_iters, dev),
-            chunk=chunk, max_chunks=max_chunks, stride=stride,
-            **engine_kw))
+        with spans.span(spans.SHARD, device=dev.id):
+            g = geoms if axis == 1 else _tree_slice(geoms, lo, hi, 0)
+            outs.append(sim.run_cells_hetero(
+                jax.device_put(g, dev),
+                jax.device_put(_tree_slice(params, lo, hi, axis), dev),
+                jax.device_put(n_iters, dev),
+                chunk=chunk, max_chunks=max_chunks, stride=stride,
+                **engine_kw))
     return ShardedOut(outs, axis)
 
 

@@ -135,3 +135,50 @@ def test_run_cells_engine_compiles_for_v5e(one_chip, monkeypatch):
         geom, params, n_iters, chunk=64, max_chunks=2, stride=8,
         backend="pallas").compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_named_scopes_leave_the_chip_engine_unchanged(one_chip, monkeypatch):
+    """The engine's named scopes are metadata on the chip too: compiled
+    for a v5e with and without them, the module's text agrees once the
+    per-op metadata and the debug tables are taken out (the Pallas
+    kernel's body included)."""
+    import contextlib
+    import re
+
+    from repro.core import bench, congestion as cong
+    from repro.core.fabric import simulator as sim, systems
+    from repro.kernels import ops as kernel_ops
+
+    monkeypatch.setattr(kernel_ops, "_default_interpret", lambda: False)
+    case = bench.build_case(systems.get_system("lumi"), 16,
+                            "ring_allreduce", "incast")
+    dt = bench.choose_dt(case.topo, case.n_victims, 1 << 20, case.lat())
+    params = sim.stack_params([case.cell_params(1 << 20, prof, dt)
+                               for prof in (cong.no_congestion(),
+                                            cong.steady())])
+    geom = jax.tree_util.tree_map(lambda x: _spec(x, one_chip), case.geom)
+    params = jax.tree_util.tree_map(lambda x: _spec(x, one_chip), params)
+    n_iters = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def compiled_text():
+        def engine(*args, **kw):       # a new function: traced anew
+            return sim._run_cells_jit.__wrapped__(*args, **kw)
+
+        return jax.jit(engine, static_argnames=(
+            "chunk", "max_chunks", "stride", "backend")).lower(
+                geom, params, n_iters, chunk=64, max_chunks=2, stride=8,
+                backend="pallas").compile().as_text()
+
+    def strip(text):
+        text = re.sub(r"\n(FileNames|FunctionNames|FileLocations|"
+                      r"StackFrames)\n(.+\n)*", "\n", text)
+        return re.sub(r",? metadata=\{[^}]*\}", "", text)
+
+    scoped = compiled_text()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = compiled_text()
+    assert "fabric_step/step_core" in scoped
+    assert "/fabric_step/" not in plain
+    assert "tpu_custom_call" in plain
+    assert strip(scoped) == strip(plain)
