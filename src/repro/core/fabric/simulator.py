@@ -756,6 +756,43 @@ def _cc_update(p: SimParams, c, a, fmark, fstrength, can_dec):
             jnp.select(preds, [d for _, d in outs], outs[0][1]))
 
 
+def _chosen(table, choice):
+    """``table[f, choice[f]]`` of an (F, K, ...) table, picked by the mask
+    ``arange(K) == choice`` over K, never by a per-lane index: under vmap
+    a gather whose indices differ per lane costs 19-27x more per element
+    on a TPU v5e than one that indexes a table every lane shares
+    (DESIGN.md §13). Exact: every other candidate meets the reduction's
+    identity (False, 0, -inf)."""
+    pick = np.arange(table.shape[1]) == choice[:, None]
+    pick = pick.reshape(pick.shape + (1,) * (table.ndim - 2))
+    if table.dtype == jnp.bool_:
+        return jnp.any(table & pick, axis=1)
+    if jnp.issubdtype(table.dtype, jnp.integer):
+        return jnp.sum(jnp.where(pick, table, 0), axis=1)
+    return jnp.max(jnp.where(pick, table, -jnp.inf), axis=1)
+
+
+def _hop_max(link_vals, geom: FabricGeometry, pad=None):
+    """(F, K): max (``any`` for booleans) of per-link values over the hops
+    of every candidate path; with ``pad`` given, pad hops read it. The
+    gather indexes only the shared candidate table, laid out (K, H, F):
+    with flows on the minor axis its tiles are dense on a TPU, which
+    took 3-14% off a step on a TPU v5e against (F, K, H)."""
+    paths = jnp.transpose(geom.paths, (1, 2, 0))
+    hops = link_vals[paths]
+    if pad is not None:
+        hops = jnp.where(paths < geom.L, hops, pad)
+    return jnp.max(hops, axis=1).T
+
+
+def _path_max(link_vals, geom: FabricGeometry, choice):
+    """Max (``any`` for booleans) of per-link values over the hops of each
+    flow's chosen path; pad hops read False / 0. The chosen candidate is
+    picked after the hop reduction (:func:`_chosen`)."""
+    return _chosen(_hop_max(link_vals, geom,
+                            pad=jnp.zeros((), link_vals.dtype)), choice)
+
+
 def step(geom: FabricGeometry, p: SimParams, state,
          backend: Optional[str] = None):
     return _step_impl(geom, p, state, with_aux=False,
@@ -840,13 +877,16 @@ def _step_sections(geom: FabricGeometry, p: SimParams, state,
         # assignments; dynamic policies score candidates by queue
         # occupancy. Under vmap the switch lowers to a select, so one
         # compile serves a grid mixing every policy. The candidate scores
-        # are hoisted out of the branches and computed ONCE — the
+        # are hoisted out of the branches and computed ONCE: the
         # dominant engine entries are batched (run_cells/_hetero evaluate
-        # every branch anyway), so sharing the (F, K, H) occupancy gather
-        # halves its per-step cost. ``occ`` is shared with the
+        # every branch anyway). Gathers here and in ``signals`` /
+        # ``queue_delay`` index only the candidate table ``geom.paths``,
+        # which every lane shares; each lane's chosen candidate is a mask
+        # over K (``_chosen``), as a per-lane gather index costs 19-27x
+        # more per element on the chip. ``occ`` is shared with the
         # backpressure stage of the core.
         occ = state["q"] / p.qmax_bytes
-        score = jnp.max(occ[geom.paths], axis=2) \
+        score = _hop_max(occ, geom) \
             + 0.05 * geom.path_len / jnp.maximum(geom.path_len[:, :1], 1)
         score = jnp.where(np.arange(geom.paths.shape[1])[None, :]
                           < geom.n_paths[:, None], score, jnp.inf)
@@ -858,7 +898,7 @@ def _step_sections(geom: FabricGeometry, p: SimParams, state,
             # least-loaded port (that herds and oscillates): a flow
             # leaves its anchor path only when its occupancy is clearly
             # worse than the best alternative.
-            a_score = jnp.take_along_axis(score, anchor[:, None], 1)[:, 0]
+            a_score = _chosen(score, anchor)
             return jnp.where(a_score > best_score + 0.10, best, anchor)
 
         def _route_adaptive(_):
@@ -888,9 +928,7 @@ def _step_sections(geom: FabricGeometry, p: SimParams, state,
         route_branches[POLICY_FLOWLET] = _route_flowlet
         choice, rc_new = jax.lax.switch(p.policy, route_branches, None)
         idle_new = jnp.where(active, 0.0, state["idle"] + dt)
-        plinks = jnp.take_along_axis(
-            geom.paths, choice[:, None, None], axis=1)[:, 0]  # (F, H)
-        valid = plinks < geom.L
+        plinks = _chosen(geom.paths, choice)  # (F, H)
 
     with jax.named_scope("step_core"):
         # Fused step core (NIC limit, backpressure stall, staged
@@ -924,13 +962,12 @@ def _step_sections(geom: FabricGeometry, p: SimParams, state,
                            0.05 * p.qmax_bytes, p.kmax * p.qmax_bytes)
         thresh = jnp.where(p.thresh_adapt > 0, adapted, state["thresh"])
         over_thresh = q > thresh
-        fmark = jnp.any(over_thresh[plinks] & valid, axis=1)
+        fmark = _path_max(over_thresh, geom, choice)
         # proportional mark strength (ai_ecn) in [0, 1]
         strength_l = jnp.clip((q - thresh)
                               / (p.kmax * p.qmax_bytes - thresh + 1.0),
                               0.0, 1.0)
-        fstrength = jnp.max(jnp.where(valid, strength_l[plinks], 0.0),
-                            axis=1)
+        fstrength = _path_max(strength_l, geom, choice)
 
     with jax.named_scope("cc"):
         # lax.switch over fabric kind
@@ -987,8 +1024,7 @@ def _step_sections(geom: FabricGeometry, p: SimParams, state,
         # queueing delay experienced by victim flows (seconds) — against
         # the fault-scaled capacity: a drained-down link serves its queue
         # slower
-        qdel = jnp.max(jnp.where(valid, (q / caps_lk)[plinks], 0.0),
-                       axis=1)
+        qdel = _path_max(q / caps_lk, geom, choice)
         mean_qdel = jnp.sum(qdel * geom.is_victim) / jnp.maximum(
             jnp.sum(geom.is_victim), 1)
         vict_goodput = jnp.sum(a * geom.is_victim)
@@ -1157,6 +1193,13 @@ def _run_cells_hetero_jit(geoms, params, n_iters, *, chunk, max_chunks,
                                  backend, metrics, with_trace)
         )(ps)
 
+    if geoms.paths.shape[0] == 1:
+        # One topology cell, as each device of the sharded sweep runs it:
+        # drop the axis rather than vmap it, so the step's gathers index
+        # a table every lane shares (DESIGN.md §13). Under vmap their
+        # indices would carry the topology axis.
+        g, ps = jax.tree_util.tree_map(lambda x: x[0], (geoms, params))
+        return jax.tree_util.tree_map(lambda x: x[None], one_geom(g, ps))
     return jax.vmap(one_geom)(geoms, params)
 
 
