@@ -2,19 +2,26 @@
 
 It answers the same question as one grid of ``repro.core.bench.run_grid``
 (a ring AllGather victim on an interleaved allocation, one baseline and
-one congested lane per vector size, steady or idle aggressor) from the
-configuration file alone: it builds the machine's topology, the
-allocation, the flows and their candidate paths, and steps the fluid
-model one lane at a time with plain scatter-adds. It imports nothing of
-the program and takes nothing the program has made.
+one congested lane per vector size) from the configuration and traffic
+files alone: it builds the machine's topology, the allocation, the flows
+and their candidate paths, and steps the fluid model one lane at a time
+with plain scatter-adds. It imports nothing of the program and takes
+nothing the program has made.
+
+What differs between fabrics and mixes lives in files of its own, found
+by name (``chipbench/spec.py``): the topology family
+(``families/<topology.family>.py``), the CC rule (``cc/<cc.kind>.py``)
+and the aggressor's envelope (``profiles/<profile>.py``; the baseline
+lane's is ``off``). The rule and the envelope are bound into a lane's
+compiled step, one program per rule and envelope.
 
 The semantics follow the paper's protocol as the program states it
 (DESIGN.md §7, §13): NIC injection limit, lossless back-pressure
-head-of-line stall, FIFO fluid sharing hop by hop, credit-following IB
+head-of-line stall, FIFO fluid sharing hop by hop, the configuration's
 rate control, adaptive routing with hysteresis around a sprayed home
 path, iterations closing when the victim's slowest flow drains, and the
 early exit checked every ``chunk`` steps. Only what the benchmark's
-traffic uses is here: no phases, faults, intra-node stage or bursts.
+traffic uses is here: no phases, faults or intra-node stage.
 
 ``dtype`` sets the precision of the step's arithmetic. The clock, the
 iteration completion times and the queueing-delay sum stay float32, so a
@@ -25,11 +32,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from chipbench import spec
+from chipbench.topology import Topology
 
 # The protocol's fixed numbers (paper §III as the program implements it).
 LAT_PER_STEP_S = 2e-6       # analytic latency per serialized schedule step
@@ -42,128 +52,21 @@ _U64 = np.uint64
 
 
 # --------------------------------------------------------------------------
-# topology: directed capacitated links and structured candidate paths
+# topology: the family file the configuration names
 # --------------------------------------------------------------------------
 
 
-@dataclasses.dataclass
-class Topology:
-    caps: np.ndarray                 # (L,) bytes/s
-    links: List[Tuple]               # (a, b) endpoints per link
-    paths: object                    # (src, dst) -> list of link-id lists
-
-
-class _Links:
-    def __init__(self):
-        self.links, self.caps, self.index = [], [], {}
-
-    def add(self, a, b, gbit):
-        if (a, b) not in self.index:
-            self.index[(a, b)] = len(self.links)
-            self.links.append((a, b))
-            self.caps.append(gbit * 1e9 / 8.0)
-
-    def __getitem__(self, key):
-        return self.index[key]
-
-
-def _host(i):
-    return ("h", i)
-
-
-def dragonfly_plus(n, leaves_per_group, spines_per_group, nodes_per_leaf,
-                   host_gbit, global_gbit, intra_factor, n_valiant):
-    b = _Links()
-    per_group = leaves_per_group * nodes_per_leaf
-    n_groups = -(-n // per_group)
-    up_gbit = (host_gbit * nodes_per_leaf / spines_per_group
-               if intra_factor <= 0 else host_gbit * intra_factor)
-
-    def leaf(i):
-        return ("lf", i // per_group, (i % per_group) // nodes_per_leaf)
-
-    for i in range(n):
-        b.add(_host(i), leaf(i), host_gbit)
-        b.add(leaf(i), _host(i), host_gbit)
-    for g in range(n_groups):
-        for lf in range(leaves_per_group):
-            for s in range(spines_per_group):
-                b.add(("lf", g, lf), ("sp", g, s), up_gbit)
-                b.add(("sp", g, s), ("lf", g, lf), up_gbit)
-    for g1 in range(n_groups):
-        for g2 in range(n_groups):
-            if g1 != g2:
-                s = (g1 + g2) % spines_per_group
-                b.add(("sp", g1, s), ("sp", g2, s), global_gbit)
-
-    def paths(src, dst):
-        ls, ld = leaf(src), leaf(dst)
-        gs, gd = ls[1], ld[1]
-        inj, ej = b[(_host(src), ls)], b[(ld, _host(dst))]
-        if ls == ld:
-            return [[inj, ej]]
-        if gs == gd:
-            return [[inj, b[(ls, ("sp", gs, s))], b[(("sp", gs, s), ld)], ej]
-                    for s in range(spines_per_group)]
-        s = (gs + gd) % spines_per_group
-        out = [[inj, b[(ls, ("sp", gs, s))], b[(("sp", gs, s), ("sp", gd, s))],
-                b[(("sp", gd, s), ld)], ej]]
-        # non-minimal paths through transit groups spread over the machine
-        stride = max(1, n_groups // (n_valiant + 1))
-        seen = {gs, gd}
-        for j in range(n_groups):
-            gi = (min(gs, gd) + 1 + j * stride) % n_groups
-            if gi in seen or len(out) >= 1 + n_valiant:
-                continue
-            seen.add(gi)
-            s1, s2 = (gs + gi) % spines_per_group, (gi + gd) % spines_per_group
-            p = [inj, b[(ls, ("sp", gs, s1))], b[(("sp", gs, s1), ("sp", gi, s1))]]
-            if s1 != s2:
-                p += [b[(("sp", gi, s1), ("lf", gi, 0))],
-                      b[(("lf", gi, 0), ("sp", gi, s2))]]
-            p += [b[(("sp", gi, s2), ("sp", gd, s2))], b[(("sp", gd, s2), ld)], ej]
-            out.append(p)
-        return out
-
-    return Topology(np.asarray(b.caps), b.links, paths)
-
-
-def fat_tree(n, nodes_per_leaf, taper, host_gbit):
-    b = _Links()
-    n_leaf = -(-n // nodes_per_leaf)
-    n_spine = max(1, round(nodes_per_leaf / taper))
-    for i in range(n):
-        lf = ("leaf", i // nodes_per_leaf)
-        b.add(_host(i), lf, host_gbit)
-        b.add(lf, _host(i), host_gbit)
-    for lf in range(n_leaf):
-        for s in range(n_spine):
-            b.add(("leaf", lf), ("spine", s), host_gbit)
-            b.add(("spine", s), ("leaf", lf), host_gbit)
-
-    def paths(src, dst):
-        ls, ld = ("leaf", src // nodes_per_leaf), ("leaf", dst // nodes_per_leaf)
-        inj, ej = b[(_host(src), ls)], b[(ld, _host(dst))]
-        if ls == ld:
-            return [[inj, ej]]
-        return [[inj, b[(ls, ("spine", s))], b[(("spine", s), ld)], ej]
-                for s in range(n_spine)]
-
-    return Topology(np.asarray(b.caps), b.links, paths)
-
-
-FAMILIES = {"dragonfly_plus": dragonfly_plus, "fat_tree": fat_tree}
-
-
 @functools.lru_cache(maxsize=4)
-def _machine(family: str, machine_nodes: int, params: tuple) -> Topology:
-    return FAMILIES[family](machine_nodes, **dict(params))
+def _machine(base: str, family: str, machine_nodes: int,
+             params: tuple) -> Topology:
+    return spec.family(family, base)(machine_nodes, **dict(params))
 
 
-def machine(config: dict) -> Topology:
+def machine(config: dict, base: str = spec.HERE) -> Topology:
+    """The whole machine, built by ``families/<topology.family>.py``."""
     topo = dict(config["topology"])
     family = topo.pop("family")
-    return _machine(family, int(config["machine_nodes"]),
+    return _machine(base, family, int(config["machine_nodes"]),
                     tuple(sorted(topo.items())))
 
 
@@ -230,8 +133,9 @@ class Case:
         return (self.n_victims - 1) * LAT_PER_STEP_S
 
 
-def build_case(config: dict, n_nodes: int, aggressor: str) -> Case:
-    topo = machine(config)
+def build_case(config: dict, n_nodes: int, aggressor: str,
+               base: str = spec.HERE) -> Case:
+    topo = machine(config, base)
     k_max = int(config["routing"]["k_max"])
     nodes = allocate(int(config["machine_nodes"]), n_nodes)
     ids = np.arange(n_nodes)
@@ -295,17 +199,20 @@ def choose_dt(case: Case, vector_bytes: float) -> float:
 # --------------------------------------------------------------------------
 
 
-def _step(g, p, s, dtype):
+def _step(g, p, s, dtype, rule, envelope):
     """One dt of the fluid model. ``g`` holds the case's arrays, ``p`` the
-    lane's scalars and byte budgets, ``s`` the state."""
+    lane's scalars, byte budgets and envelope arguments, ``s`` the state;
+    ``rule`` is the CC rule's ``update`` and ``envelope`` the aggressor
+    profile's."""
     f = lambda x: jnp.asarray(x, dtype)  # noqa: E731
     L = g["caps"].shape[0] - 1
     dt = f(p["dt"])
     qmax = f(p["qmax_bytes"])
     vict = g["is_victim"]
+    env = envelope(s["t"], **p["env_args"])
     alive = s["rem"] > 0
-    active = (vict | (p["env"] > 0)) & alive
-    inject = s["c"] * f(jnp.where(vict, 1.0, p["env"])) * f(alive)
+    active = (vict | (env > 0)) & alive
+    inject = s["c"] * f(jnp.where(vict, 1.0, env)) * f(alive)
 
     # adaptive routing: least-occupied candidate, kept off the home path
     # unless the home path is clearly worse
@@ -351,17 +258,14 @@ def _step(g, p, s, dtype):
                  f(0), qmax)
     q = q.at[L].set(f(0))
 
-    # IB rate control: the window follows what drains, marks cut it
+    # rate control: the configuration's CC rule on its marks and timers
     thresh = f(p["kmin"]) * qmax
     mark = jnp.any((q > thresh)[plinks] & valid, axis=1)
     can_dec = s["last_dec"] >= p["cc_interval_s"]
     hc = f(p["host_caps"])
     follow = f(1) - jnp.exp(-dt / jnp.maximum(f(p["follow_tau_s"]), f(1e-9)))
-    c2 = (f(1) - follow) * s["c"] + follow * jnp.maximum(
-        r * f(p["follow_gain"]), f(p["min_rate_frac"]) * hc)
-    dec = mark & can_dec
-    c = jnp.where(dec, c2 * f(p["md"]),
-                  c2 + f(p["rai_frac"]) * hc * (dt / f(1e-3)))
+    inc = f(p["rai_frac"]) * hc * (dt / f(1e-3))
+    c, dec = rule(s["c"], r, mark, can_dec, follow, inc, hc, p)
     c = jnp.where(active, c, s["c"])
     dec = dec & active
     c = jnp.clip(c, f(p["min_rate_frac"]) * hc, hc)
@@ -385,8 +289,10 @@ def _step(g, p, s, dtype):
 
 
 @functools.partial(jax.jit, static_argnames=("n_sw", "n_src", "chunk",
-                                             "max_chunks", "dtype"))
-def _run_lane(g, p, n_iters, *, n_sw, n_src, chunk, max_chunks, dtype):
+                                             "max_chunks", "dtype", "rule",
+                                             "envelope"))
+def _run_lane(g, p, n_iters, *, n_sw, n_src, chunk, max_chunks, dtype, rule,
+              envelope):
     g = dict(g, n_sw=n_sw, n_src=n_src)
     F = g["is_victim"].shape[0]
     state = {"c": jnp.asarray(p["host_caps"], dtype),
@@ -404,7 +310,8 @@ def _run_lane(g, p, n_iters, *, n_sw, n_src, chunk, max_chunks, dtype):
 
     def body(carry):
         s, k = carry
-        s = jax.lax.fori_loop(0, chunk, lambda _, x: _step(g, p, x, dtype), s)
+        s = jax.lax.fori_loop(
+            0, chunk, lambda _, x: _step(g, p, x, dtype, rule, envelope), s)
         return s, k + 1
 
     s, k = jax.lax.while_loop(cond, body, (state, jnp.zeros((), jnp.int32)))
@@ -412,11 +319,14 @@ def _run_lane(g, p, n_iters, *, n_sw, n_src, chunk, max_chunks, dtype):
             "qd_acc": s["qd_acc"], "chunks": k}
 
 
-def launch_lane(case: Case, cc: dict, vector_bytes: float, env: float,
+def launch_lane(case: Case, cc: dict, vector_bytes: float, profile: str,
                 dt: float, *, n_iters: int, chunk: int, max_chunks: int,
-                dtype=jnp.float32, device=None) -> dict:
+                profile_args: dict = None, dtype=jnp.float32, device=None,
+                base: str = spec.HERE) -> dict:
     """Dispatch one lane, stepped until its victim has closed ``n_iters``
-    iterations, checked every ``chunk`` steps. Returns device arrays."""
+    iterations, checked every ``chunk`` steps. The aggressor follows
+    ``profiles/<profile>.py`` with ``profile_args``, and the flows' rates
+    ``cc/<cc["kind"]>.py``. Returns device arrays."""
     bytes_ = np.where(case.is_victim, case.unit_bytes * vector_bytes,
                       case.unit_bytes)
     g = {"caps": case.caps.astype(np.float32),
@@ -429,13 +339,16 @@ def launch_lane(case: Case, cc: dict, vector_bytes: float, env: float,
          "src": case.src.astype(np.int32),
          "is_victim": case.is_victim}
     p = {k: np.float32(v) for k, v in cc.items() if k != "kind"}
-    p.update(dt=np.float32(dt), env=np.float32(env),
+    p.update(dt=np.float32(dt),
+             env_args={k: np.float32(v)
+                       for k, v in (profile_args or {}).items()},
              host_caps=case.host_caps.astype(np.float32),
              bytes=bytes_.astype(np.float32))
     g, p = jax.device_put((g, p), device)
     return _run_lane(g, p, np.int32(n_iters), n_sw=case.n_sw,
                      n_src=case.n_src, chunk=chunk, max_chunks=max_chunks,
-                     dtype=dtype)
+                     dtype=dtype, rule=spec.cc_rule(cc["kind"], base),
+                     envelope=spec.profile(profile, base).envelope)
 
 
 # --------------------------------------------------------------------------
@@ -461,38 +374,45 @@ def lane_answer(out, lat: float, n_iters: int, warmup: int):
 _CASES: Dict[tuple, Case] = {}
 
 
-def cached_case(config: dict, n_nodes: int, aggressor: str) -> Case:
-    key = (config["name"], int(n_nodes), aggressor)
+def cached_case(config: dict, n_nodes: int, aggressor: str,
+                base: str = spec.HERE) -> Case:
+    key = (base, config["name"], int(n_nodes), aggressor)
     if key not in _CASES:
-        _CASES[key] = build_case(config, n_nodes, aggressor)
+        _CASES[key] = build_case(config, n_nodes, aggressor, base)
     return _CASES[key]
 
 
 def answer(config: dict, nodes: Sequence[int], aggressor: str,
            sizes: Sequence[float], *, n_iters: int, warmup: int, chunk: int,
-           max_steps: int, dtype=jnp.float32, devices=(None,)) -> List[dict]:
+           max_steps: int, profile: str = "steady", profile_args: dict = None,
+           dtype=jnp.float32, devices=(None,),
+           base: str = spec.HERE) -> List[dict]:
     """One grid's rows, allocation sizes major, then vector sizes: per
     size the baseline lane (aggressor idle) and the congested lane
-    (aggressor steady), as ``{vector_bytes, dt, t_uncongested_s,
+    (aggressor on ``profile``, a traffic mix's ``profile`` and
+    ``profile_args``), as ``{vector_bytes, dt, t_uncongested_s,
     t_congested_s, ratio, n_iters, victim_mean_s}``. Lanes are
-    dispatched round-robin over ``devices`` before any is read back."""
+    dispatched round-robin over ``devices`` before any is read back.
+    ``base`` holds the part files the configuration and profile name."""
     cc = config["cc"]
     lanes = []
     for n in nodes:
-        case = cached_case(config, n, aggressor)
+        case = cached_case(config, n, aggressor, base)
         for v in sizes:
             dt = choose_dt(case, float(v))
-            for env in (0.0, 1.0):
+            for prof, args in ((spec.BASELINE_PROFILE, None),
+                               (profile, profile_args)):
                 dev = devices[len(lanes) % len(devices)]
                 lanes.append((case, float(v), dt, launch_lane(
-                    case, cc, float(v), env, dt, n_iters=n_iters,
+                    case, cc, float(v), prof, dt, n_iters=n_iters,
                     chunk=chunk, max_chunks=-(-max_steps // chunk),
-                    dtype=dtype, device=dev)))
+                    profile_args=args, dtype=dtype, device=dev,
+                    base=base)))
     rows = []
-    for (case, v, dt, base), (_, _, _, cong) in zip(lanes[::2], lanes[1::2]):
+    for (case, v, dt, idle), (_, _, _, cong) in zip(lanes[::2], lanes[1::2]):
         (n_u, t_u, _), (n_c, t_c, mean_c) = [
             lane_answer(jax.tree_util.tree_map(np.asarray, out), case.lat,
-                        n_iters, warmup) for out in (base, cong)]
+                        n_iters, warmup) for out in (idle, cong)]
         rows.append({"vector_bytes": v, "dt": dt,
                      "t_uncongested_s": t_u, "t_congested_s": t_c,
                      "ratio": t_u / t_c if n_u and n_c else float("nan"),

@@ -37,7 +37,6 @@ import tempfile  # noqa: E402
 from chipbench import check, questions, roofline, spec  # noqa: E402
 from chipbench import trace as trace_lib  # noqa: E402
 
-PROFILES = {"steady": "steady", "off": "no_congestion"}
 # the engine protocol of a grid as scenarios.run_grid_spec runs it
 # (bench.run_grid's defaults): the early exit is checked every CHUNK steps
 CHUNK, MAX_STEPS = 2048, 200_000
@@ -83,7 +82,8 @@ def ask(cell: dict, sizes, *, n_iters: int, warmup: int, mesh=None):
     from repro.core.fabric import systems
 
     tr, cfg = cell["traffic"], cell["config"]
-    profiles = (getattr(cong, PROFILES[tr["profile"]])(),)
+    program = spec.profile(tr["profile"], cell["base"]).PROGRAM
+    profiles = (getattr(cong, program)(**tr.get("profile_args", {})),)
     if len(tr["nodes"]) == 1 and mesh is None:
         system = systems.get_system(cfg["preset"])
         return bench.run_grid(system, tr["nodes"][0], tr["victim"],
@@ -125,7 +125,9 @@ def reference_check(cell: dict, answers, seed: int, devices) -> dict:
         rows = reference.answer(cfg, tr["nodes"], tr["aggressor"],
                                 answers[i].sizes, n_iters=cfg["n_iters"],
                                 warmup=cfg["warmup"], chunk=CHUNK,
-                                max_steps=MAX_STEPS, devices=list(devices))
+                                max_steps=MAX_STEPS, profile=tr["profile"],
+                                profile_args=tr.get("profile_args"),
+                                devices=list(devices), base=cell["base"])
         readings.append(check.compare(answers[i].results, rows))
     return check.worst(readings)
 

@@ -1,6 +1,7 @@
 """The benchmark's own files, checked on the CPU (no chip).
 
-Every configuration, traffic mix and metric reader loads; BENCHMARK.json
+Every configuration, traffic mix, metric reader and part of the
+reference (topology family, CC rule, envelope profile) loads; BENCHMARK.json
 keeps to its character rules; a new traffic file is found by its name
 alone; the step core's bytes match the kernel's operands; the reference
 answers as the program does at a small size; and a run that finds no
@@ -39,9 +40,18 @@ def test_every_file_loads(bench):
         assert questions.Questions(tr, 1).sizes(0)
         assert set(tr["check"]["limits"]) == {"time_gap_steps", "ratio_gap",
                                               "count_mismatch"}
-    for name in sorted(os.listdir(os.path.join(spec.HERE, "metrics"))):
+    for kind, load in (("metrics", spec.metric_reader),
+                       ("families", spec.family), ("cc", spec.cc_rule),
+                       ("profiles", lambda n: spec.profile(n).envelope)):
+        for name in sorted(os.listdir(os.path.join(spec.HERE, kind))):
+            if name.endswith(".py"):
+                assert callable(load(name[:-len(".py")]))
+    from repro.core import congestion
+
+    for name in sorted(os.listdir(os.path.join(spec.HERE, "profiles"))):
         if name.endswith(".py"):
-            assert callable(spec.metric_reader(name[:-len(".py")]))
+            program = spec.profile(name[:-len(".py")]).PROGRAM
+            assert callable(getattr(congestion, program))
     for w in bench["workloads"]:
         cell = spec.cell(w["name"], bench)
         assert cell["end_to_end"] and cell["per_layer"]

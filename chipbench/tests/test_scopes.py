@@ -200,7 +200,7 @@ def test_sample_on_the_cpu_reads_spans_and_the_engine_text():
     run = _Run()
     run.cell = {"config": spec.config("cresco8"),
                 "traffic": dict(spec.traffic("incast-256"), nodes=[16]),
-                "workload": {"chips": 1}}
+                "workload": {"chips": 1}, "base": spec.HERE}
     s = scopes._take_sample(run)
     assert s["lane_steps"] > 0
     assert {"fabric.build_case", "fabric.grid_params", "fabric.dispatch",
